@@ -65,9 +65,7 @@ def bench_modes(
     tcfg = TiledDecoderConfig()
 
     def run_tiled():
-        return jax.vmap(
-            lambda x: decoder.decode_stream_tiled(x, tcfg)
-        )(llrs)
+        return decoder.decode_streams_tiled(llrs, tcfg)
 
     def run_chunked():
         return decoder.decode_stream_chunked(

@@ -280,6 +280,9 @@ def main() -> None:
         help="where BENCH_<suite>.json artifacts land (default: repo root)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from benchmarks import (
         bench_ber,

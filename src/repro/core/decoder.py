@@ -52,7 +52,7 @@ from .viterbi import (
     decode_frames,
     forward_fused,
     init_metric,
-    tiled_decode_stream,
+    tiled_decode_streams,
     traceback,
 )
 
@@ -678,6 +678,18 @@ class ViterbiDecoder:
     ) -> jnp.ndarray:
         """Overlapping-window decode of one stream (paper §III): (n, beta),
         or the serial punctured (Lp,) stream for a punctured decoder.
+        ``decode_streams_tiled`` on a batch of one."""
+        return self.decode_streams_tiled(jnp.asarray(llrs)[None], cfg)[0]
+
+    def decode_streams_tiled(
+        self,
+        llrs: jnp.ndarray,
+        cfg: Optional[TiledDecoderConfig] = None,
+    ) -> jnp.ndarray:
+        """Overlapping-window decode of N streams (paper §III): (N, n,
+        beta), or the serial punctured (N, Lp) streams for a punctured
+        decoder -> (N, n).  The windows of all streams decode as one
+        frame batch.
 
         When no cfg is given, a punctured decoder stretches the default
         overlap by the puncture expansion (erasure-aware accounting,
@@ -689,12 +701,12 @@ class ViterbiDecoder:
                 "tiled stream decode assumes an open (non-circular) "
                 "trellis; use decode_batch/decode_tailbiting per frame"
             )
-        llrs = self._harden(self.depunctured(llrs, stream=True))
+        llrs = self._harden(self.depunctured(llrs))
         cfg = cfg or self.default_tiled_config()
         if cfg.rho != self.rho:
             raise ValueError(f"cfg.rho={cfg.rho} != decoder rho={self.rho}")
         _count_dispatch("tiled")
-        return tiled_decode_stream(
+        return tiled_decode_streams(
             llrs,
             self.spec,
             cfg,
@@ -750,6 +762,9 @@ class ViterbiDecoder:
             self.ring_packed,
             self.time_tile,
             self.block_frames,
+            self.tables.llr_block,
+            self.tables.n_slots,
+            self.precision.matmul_dtype,
         )
 
     def decode_chunk(

@@ -18,7 +18,11 @@ __all__ = [
     "DEFAULT_BLOCK_FRAMES",
     "DEFAULT_TIME_TILE",
     "DEFAULT_TRANSFER_TILE",
-    "FUSED_RING_VMEM_BUDGET",
+    "DEFAULT_FORWARD_TIME_TILE",
+    "VMEM_CAPACITY_BYTES",
+    "DEFAULT_SCOPED_VMEM_BYTES",
+    "VMEM_HEADROOM_BYTES",
+    "KERNEL_VMEM_BUDGET",
     "MIN_ONE_PASS_TILE",
     "MIN_TIME_PARALLEL_TILES",
     "ring_words",
@@ -26,10 +30,16 @@ __all__ = [
     "ring_auto_packed",
     "pick_time_tile",
     "one_pass_time_tile",
+    "vmem_bytes",
+    "vmem_limit_bytes",
     "fused_ring_vmem_bytes",
+    "fused_decode_vmem_bytes",
+    "forward_vmem_bytes",
+    "forward_time_tile",
     "default_transfer_tile",
     "pick_transfer_tile",
     "time_parallel_plan",
+    "transfer_block_frames",
     "transfer_tile_vmem_bytes",
     "ENGINE_MIN_CELL",
     "pick_cell_length",
@@ -38,11 +48,22 @@ __all__ = [
 
 DEFAULT_BLOCK_FRAMES = 256
 DEFAULT_TIME_TILE = 32
+# two-pass kernel: radix steps per grid program — the path-metric carry
+# stays in VMEM across time tiles and survivors stream out tile by tile,
+# so VMEM use is bounded by the tile, never by the frame length
+DEFAULT_FORWARD_TIME_TILE = 128
 
-# one-pass decoding keeps decision_depth + time_tile steps of survivors
-# resident in VMEM (DESIGN.md §8); rings beyond this budget must fall
-# back to the two-pass kernel rather than blowing the ~16MB core
-FUSED_RING_VMEM_BUDGET = 12 * 2**20
+# VMEM as Mosaic sees it on a TPU v5e core: a kernel whose buffers
+# exceed the scoped limit it was compiled with fails RESOURCE_EXHAUSTED,
+# and the limit can be raised per kernel up to the physical capacity.
+# The headroom covers Mosaic's own temporaries (matmul results, spills).
+VMEM_CAPACITY_BYTES = 128 * 2**20
+DEFAULT_SCOPED_VMEM_BYTES = 16 * 2**20
+VMEM_HEADROOM_BYTES = 8 * 2**20
+# every kernel's counted footprint must stay under this; shapes beyond
+# it are refused up front (one-pass falls back to two-pass, the other
+# kernels shrink their tile or raise) rather than at Mosaic compile time
+KERNEL_VMEM_BUDGET = VMEM_CAPACITY_BYTES - VMEM_HEADROOM_BYTES
 
 # below this time tile the one-pass kernel degenerates (a near-full ring
 # traceback per tiny tile): both streaming entry points fall back to the
@@ -92,6 +113,53 @@ def pick_time_tile(d_steps: int, t_steps: int, target=None) -> int:
     return best
 
 
+def vmem_bytes(shape, dtype) -> int:
+    """Bytes one VMEM buffer of ``shape`` takes as Mosaic lays it out on
+    a TPU v5e: the minor dim padded to 128 lanes; the second-minor dim
+    rounded up to a multiple of 8 rows, or, when it holds 4 rows or
+    fewer, to a power of two of at least one 32-bit sublane word (1 row
+    of 32-bit, 2 of 16-bit, 4 of 8-bit); leading dims unpadded.  So a
+    (2592, 256, 4) int32 buffer takes 339,738,624 bytes (s32[2592,256,128]
+    in Mosaic's allocation report), not the 10.6 MB its elements hold,
+    while (2592, 4, 256) int32 takes exactly its 10,616,832 bytes."""
+    itemsize = jnp.dtype(dtype).itemsize
+    dims = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
+    rows = dims[-2]
+    if rows <= 4:
+        second = max(1 << (rows - 1).bit_length(), 4 // itemsize)
+    else:
+        second = -(-rows // 8) * 8
+    minor = -(-dims[-1] // 128) * 128
+    return math.prod(dims[:-2]) * second * minor * itemsize
+
+
+def vmem_limit_bytes(need: int):
+    """Scoped-VMEM limit to compile a kernel of footprint ``need`` with:
+    None (the compiler default) when it fits the default scoped limit,
+    else the footprint plus headroom, capped at the physical capacity —
+    the limit is raised only for kernels that really need it."""
+    want = int(need) + VMEM_HEADROOM_BYTES
+    if want <= DEFAULT_SCOPED_VMEM_BYTES:
+        return None
+    return min(want, VMEM_CAPACITY_BYTES)
+
+
+def _acs_operand_bytes(time_tile, block_frames, n_states, llr_block,
+                       n_slots, matmul_dtype) -> int:
+    """VMEM shared by both frame-batched ACS kernels (frames on lanes):
+    the double-buffered (TT, B, BF) LLR blocks, the entry/exit metric
+    blocks and the carry scratch, the slot-major weights, and the
+    (R*S, BF) potentials of one step."""
+    S, B, R, BF = n_states, llr_block, n_slots, block_frames
+    return (
+        2 * vmem_bytes((time_tile, B, BF), matmul_dtype)
+        + 5 * vmem_bytes((S, BF), jnp.float32)
+        + 2 * vmem_bytes((R * S, B), matmul_dtype)
+        + 2 * vmem_bytes((R * S, S), matmul_dtype)
+        + 3 * vmem_bytes((R * S, BF), jnp.float32)
+    )
+
+
 def fused_ring_vmem_bytes(
     depth_steps: int,
     time_tile: int,
@@ -99,15 +167,87 @@ def fused_ring_vmem_bytes(
     n_states: int,
     pack_survivors: bool,
 ) -> int:
-    """VMEM footprint of the one-pass kernel's survivor ring, in bytes —
-    the term that bounds usable decision depths (DESIGN.md §8 table)."""
-    itemsize = jnp.dtype(ring_dtype(pack_survivors)).itemsize
-    return (
-        (depth_steps + time_tile)
-        * block_frames
-        * ring_words(n_states, pack_survivors)
-        * itemsize
+    """VMEM footprint of the one-pass kernel's (D+TT, W, BF) survivor
+    ring, frames on lanes — the term that bounds usable decision depths
+    (DESIGN.md §8 table)."""
+    return vmem_bytes(
+        (depth_steps + time_tile,
+         ring_words(n_states, pack_survivors),
+         block_frames),
+        ring_dtype(pack_survivors),
     )
+
+
+def fused_decode_vmem_bytes(
+    depth_steps: int,
+    time_tile: int,
+    block_frames: int,
+    n_states: int,
+    llr_block: int,
+    n_slots: int,
+    pack_survivors: bool,
+    matmul_dtype=jnp.float32,
+) -> int:
+    """Whole VMEM footprint of one ``acs_decode_fused_pallas`` program:
+    the survivor ring plus the ACS operands and the (TT/G, G, BF)
+    decision block (the entry/exit rings stay in HBM, moved by DMA)."""
+    g = math.gcd(time_tile, 8)
+    return (
+        fused_ring_vmem_bytes(
+            depth_steps, time_tile, block_frames, n_states, pack_survivors
+        )
+        + _acs_operand_bytes(time_tile, block_frames, n_states, llr_block,
+                             n_slots, matmul_dtype)
+        + 2 * vmem_bytes((time_tile // g, g, block_frames), jnp.int32)
+    )
+
+
+def forward_vmem_bytes(
+    time_tile: int,
+    block_frames: int,
+    n_states: int,
+    llr_block: int,
+    n_slots: int,
+    pack_survivors: bool,
+    matmul_dtype=jnp.float32,
+) -> int:
+    """VMEM footprint of one ``acs_forward_pallas`` program: the ACS
+    operands plus the double-buffered (TT, W, BF) survivor block."""
+    return (
+        _acs_operand_bytes(time_tile, block_frames, n_states, llr_block,
+                           n_slots, matmul_dtype)
+        + 2 * vmem_bytes(
+            (time_tile, ring_words(n_states, pack_survivors), block_frames),
+            ring_dtype(pack_survivors),
+        )
+    )
+
+
+def forward_time_tile(
+    t_steps: int,
+    block_frames: int,
+    n_states: int,
+    llr_block: int,
+    n_slots: int,
+    pack_survivors: bool,
+    matmul_dtype=jnp.float32,
+) -> int:
+    """The two-pass kernel's VMEM guard: the largest time tile <=
+    ``DEFAULT_FORWARD_TIME_TILE`` (capped at the step count) whose
+    footprint fits ``KERNEL_VMEM_BUDGET``, halving from there.  Raises
+    when not even one step per program fits."""
+    tt = max(1, min(DEFAULT_FORWARD_TIME_TILE, t_steps))
+    args = (block_frames, n_states, llr_block, n_slots, pack_survivors,
+            matmul_dtype)
+    while tt > 1 and forward_vmem_bytes(tt, *args) > KERNEL_VMEM_BUDGET:
+        tt //= 2
+    need = forward_vmem_bytes(tt, *args)
+    if need > KERNEL_VMEM_BUDGET:
+        raise ValueError(
+            f"two-pass kernel needs {need} bytes of VMEM at one step per "
+            f"program (budget {KERNEL_VMEM_BUDGET}); lower block_frames"
+        )
+    return tt
 
 
 def default_transfer_tile(t_steps: int) -> int:
@@ -167,24 +307,35 @@ def time_parallel_plan(
     return tt if n_frames * n_states <= underfill_rows else None
 
 
+def transfer_block_frames(n_frames: int, n_states: int) -> int:
+    """Frames per ``transfer_matrix_pallas`` program: enough to give the
+    (S*FB, S) matrix carry ~512 MXU rows, a multiple of 8 (the sublane
+    tile the entry-major row layout needs), never more than the frame
+    count rounded up to 8."""
+    target = max(8, (512 // n_states) // 8 * 8)
+    return min(target, -(-n_frames // 8) * 8)
+
+
 def transfer_tile_vmem_bytes(
     time_tile: int,
     block_frames: int,
     n_states: int,
     llr_block: int,
     n_slots: int,
-    matmul_itemsize: int = 4,
 ) -> int:
-    """VMEM footprint of one ``transfer_matrix_pallas`` program: the
-    tile's LLR blocks, the (BF*S, S) matrix carry, the stacked operand W
-    and the (BF*S, S*R) potentials — the term that bounds usable
-    transfer tiles on-chip (DESIGN.md §9 table)."""
-    rows = block_frames * n_states
+    """VMEM footprint of one ``transfer_matrix_pallas`` program as Mosaic
+    lays it out: the double-buffered (TT, FB, B) LLR blocks and per-slot
+    weights, the (S*FB, S) matrix carry with the per-slot potentials of
+    one step, and the double-buffered (S, FB, S) result — the term that
+    bounds usable transfer tiles on-chip (DESIGN.md §9 table)."""
+    S, B, R, FB = n_states, llr_block, n_slots, block_frames
+    f32 = jnp.float32
     return (
-        time_tile * block_frames * llr_block * matmul_itemsize  # blocks
-        + rows * n_states * 4  # matrix carry (f32)
-        + (llr_block + n_states) * n_states * n_slots * matmul_itemsize  # W
-        + rows * n_states * n_slots * 4  # potentials (f32 accumulate)
+        2 * vmem_bytes((time_tile, FB, B), f32)
+        + 2 * vmem_bytes((R, B, S), f32)
+        + 2 * vmem_bytes((R, S, S), f32)
+        + (R + 2) * vmem_bytes((S * FB, S), f32)
+        + 2 * vmem_bytes((S, FB, S), f32)
     )
 
 
@@ -229,13 +380,17 @@ def one_pass_time_tile(
     ring_packed: bool,
     time_tile=None,
     block_frames=None,
+    llr_block: int = 4,
+    n_slots: int = 4,
+    matmul_dtype=jnp.float32,
 ):
     """Shared one-pass eligibility check for every streaming entry point
     (decoder.decode_chunk and the tiled window path): the time tile to
     launch the fused kernel with, or None when the shape should take the
     two-pass fallback — packing impossible, no usable common tile (a
-    time_tile~1 kernel walks the whole ring per step), or a survivor
-    ring beyond the VMEM budget."""
+    time_tile~1 kernel walks the whole ring per step), or a kernel
+    footprint (ring included, counted as Mosaic lays it out) beyond the
+    VMEM budget."""
     if d_steps <= 0 or t_steps <= 0:
         return None
     if ring_packed and n_states % 16:
@@ -245,8 +400,9 @@ def one_pass_time_tile(
         return None
     bf = block_frames or DEFAULT_BLOCK_FRAMES
     if (
-        fused_ring_vmem_bytes(d_steps, tt, bf, n_states, ring_packed)
-        > FUSED_RING_VMEM_BUDGET
+        fused_decode_vmem_bytes(d_steps, tt, bf, n_states, llr_block,
+                                n_slots, ring_packed, matmul_dtype)
+        > KERNEL_VMEM_BUDGET
     ):
         return None
     return tt

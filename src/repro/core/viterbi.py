@@ -43,6 +43,8 @@ from .kernel_geometry import (  # noqa: F401 — pallas-free geometry + re-expor
 from .semiring import NEG, TROPICAL, Semiring
 from .trellis import AcsTables, CodeSpec, build_acs_tables
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "AcsPrecision",
     "forward_fused",
@@ -52,6 +54,7 @@ __all__ = [
     "decode_frames",
     "TiledDecoderConfig",
     "tiled_decode_stream",
+    "tiled_decode_streams",
     "blocks_from_llrs",
     "pick_time_tile",
     "NEG",
@@ -115,16 +118,21 @@ def fused_potentials(
     precision: AcsPrecision,
 ) -> jnp.ndarray:
     """One fused-ACS matmul (DESIGN.md §2): branch metrics + path-metric
-    routing in a single MXU op, f32 accumulation.  Shared by the
+    routing in a single MXU op, f32 accumulation.  f32 operands are
+    multiplied at ``Precision.HIGHEST``: a TPU's default f32 dot rounds
+    its inputs to bf16, which would quietly quantize the path metrics
+    the precision policy keeps in f32 (a no-op on CPU).  Shared by the
     sequential scan and the §9 transfer-matrix formation so the two
     paths quantize identically.  Returns (rows, S*R) f32 potentials."""
     if precision.split_dot:
         return jnp.dot(
             l_t.astype(precision.matmul_dtype),
             w_theta,
+            precision=_HIGHEST,
             preferred_element_type=jnp.float32,
         ) + jnp.dot(
             lam.astype(jnp.float32), w_pred,
+            precision=_HIGHEST,
             preferred_element_type=jnp.float32,
         )
     x = jnp.concatenate(
@@ -132,7 +140,9 @@ def fused_potentials(
          lam.astype(precision.matmul_dtype)],
         axis=1,
     )
-    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+    return jnp.dot(
+        x, w, precision=_HIGHEST, preferred_element_type=jnp.float32
+    )
 
 
 def blocks_from_llrs(llrs: jnp.ndarray, rho: int) -> jnp.ndarray:
@@ -331,6 +341,7 @@ class TiledDecoderConfig:
 def _one_pass_window_plan(
     spec: CodeSpec,
     cfg: TiledDecoderConfig,
+    precision: AcsPrecision,
     pack_survivors: bool,
     time_tile: Optional[int],
     block_frames: Optional[int],
@@ -347,7 +358,8 @@ def _one_pass_window_plan(
     packed = ring_auto_packed(spec.n_states, pack_survivors)
     tt = one_pass_time_tile(
         v // rho, cfg.window // rho, spec.n_states, packed,
-        time_tile, block_frames,
+        time_tile, block_frames, rho * spec.beta, 1 << rho,
+        precision.matmul_dtype,
     )
     return None if tt is None else (tt, packed)
 
@@ -402,6 +414,17 @@ def tiled_decode_stream(
     llrs: jnp.ndarray,
     spec: CodeSpec,
     cfg: TiledDecoderConfig = TiledDecoderConfig(),
+    **kw,
+) -> jnp.ndarray:
+    """One stream (n, beta) -> (n,) bits: ``tiled_decode_streams`` on a
+    batch of one (keyword arguments as there)."""
+    return tiled_decode_streams(llrs[None], spec, cfg, **kw)[0]
+
+
+def tiled_decode_streams(
+    llrs: jnp.ndarray,
+    spec: CodeSpec,
+    cfg: TiledDecoderConfig = TiledDecoderConfig(),
     precision: AcsPrecision = AcsPrecision(),
     use_kernel: bool = False,
     pack_survivors: bool = False,
@@ -411,12 +434,14 @@ def tiled_decode_stream(
     time_parallel: Optional[bool] = None,
     transfer_tile: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Decode one long LLR stream (n, beta) via overlapping parallel frames.
+    """Decode N long LLR streams (N, n, beta) -> (N, n) via overlapping
+    parallel frames; the windows of every stream form one frame batch.
 
-    The stream is zero-LLR padded by `overlap` on both ends, sliced into
-    n/frame_len windows of length frame_len + 2*overlap, all windows decoded
-    in parallel (truncated Viterbi: uniform start metric, argmax end state),
-    and the center frame_len decisions of each window are stitched together.
+    Each stream is zero-LLR padded by `overlap` on both ends, sliced into
+    n/frame_len windows of length frame_len + 2*overlap, all windows of
+    all streams decoded in parallel (truncated Viterbi: uniform start
+    metric, argmax end state), and the center frame_len decisions of each
+    window are stitched back together per stream.
 
     With ``one_pass=True`` the windows run through the time-tiled
     ACS+traceback kernel (DESIGN.md §8): survivors stay in a VMEM ring
@@ -439,22 +464,23 @@ def tiled_decode_stream(
     the one-pass kernel plan; on auto, an eligible one-pass plan wins
     (same depth class per window, none of the S x formation work).
     """
-    n, beta = llrs.shape
+    n_streams, n, beta = llrs.shape
     f, v = cfg.frame_len, cfg.overlap
-    n_frames = -(-n // f)  # ceil
-    padded_len = n_frames * f + 2 * v
+    n_win = -(-n // f)  # windows per stream (ceil)
+    n_frames = n_streams * n_win
+    padded_len = n_win * f + 2 * v
     pad_lo = v
     pad_hi = padded_len - n - v
-    padded = jnp.pad(jnp.asarray(llrs), ((pad_lo, pad_hi), (0, 0)))
-    idx = jnp.arange(n_frames)[:, None] * f + jnp.arange(cfg.window)[None, :]
-    frames = padded[idx]  # (n_frames, window, beta)
+    padded = jnp.pad(jnp.asarray(llrs), ((0, 0), (pad_lo, pad_hi), (0, 0)))
+    idx = jnp.arange(n_win)[:, None] * f + jnp.arange(cfg.window)[None, :]
+    frames = padded[:, idx].reshape(n_frames, cfg.window, beta)
     tp_tile = time_parallel_plan(
         n_frames, cfg.window // cfg.rho, spec.n_states,
         time_parallel, transfer_tile,
     )
     plan = (
         _one_pass_window_plan(
-            spec, cfg, pack_survivors, time_tile, block_frames
+            spec, cfg, precision, pack_survivors, time_tile, block_frames
         )
         if one_pass else None
     )
@@ -465,7 +491,7 @@ def tiled_decode_stream(
         center = _one_pass_windows(
             frames, spec, cfg, precision, plan[0], plan[1], block_frames,
         )
-        return center.reshape(-1)[:n]
+        return center.reshape(n_streams, -1)[:, :n]
     if tp_tile is not None:
         from .timeparallel import decode_time_parallel
 
@@ -492,4 +518,4 @@ def tiled_decode_stream(
             pack_survivors=pack_survivors,
         )
     center = decoded[:, v : v + f]  # (n_frames, f)
-    return center.reshape(-1)[:n]
+    return center.reshape(n_streams, -1)[:, :n]

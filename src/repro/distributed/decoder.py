@@ -38,7 +38,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.kernel_geometry import pick_transfer_tile
@@ -50,7 +49,7 @@ from repro.core.viterbi import (
     blocks_from_llrs,
     forward_fused,
     init_metric,
-    tiled_decode_stream,
+    tiled_decode_streams,
     traceback,
 )
 
@@ -144,9 +143,9 @@ def _frames_fn(
         return traceback(phis, fs, tables)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -196,8 +195,8 @@ def _streams_fn(
     time_tile,
     block_frames,
 ):
-    decode_one = functools.partial(
-        tiled_decode_stream,
+    decode_local = functools.partial(
+        tiled_decode_streams,
         spec=spec,
         cfg=cfg,
         precision=precision,
@@ -208,12 +207,12 @@ def _streams_fn(
         block_frames=block_frames,
     )
     return jax.jit(
-        shard_map(
-            jax.vmap(decode_one),
+        jax.shard_map(
+            decode_local,
             mesh=mesh,
             in_specs=P(axis),
             out_specs=P(axis),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -233,8 +232,9 @@ def sharded_decode_streams(
 ) -> jnp.ndarray:
     """Serve-shape decode: (N, n, beta) streams, stream axis sharded.
 
-    Each device runs the tiled window decoder (vmapped over its local
-    streams); equals jax.vmap(tiled_decode_stream) on one device.  With
+    Each device runs the tiled window decoder over its local streams
+    (their windows form one frame batch); equals ``tiled_decode_streams``
+    on one device.  With
     ``one_pass=True`` every shard's windows run through the time-tiled
     ACS+traceback kernel (DESIGN.md §8) — the per-device program is still
     exactly the single-device program, so numerics stay bit-identical to
@@ -337,12 +337,12 @@ def _time_parallel_fn(
         ).reshape(F, t_loc * rho)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=P(axis),
             out_specs=P(None, axis),
-            check_rep=False,
+            check_vma=False,
         )
     )
 

@@ -20,7 +20,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
@@ -88,10 +87,10 @@ def pipeline_apply(
         out = jax.lax.psum(out, axis)
         return out.reshape((-1,) + x.shape[1:])
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

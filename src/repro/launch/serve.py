@@ -98,7 +98,11 @@ def _viterbi_run_fn(vcfg, args):
     raise ValueError(f"unknown --mode {args.mode!r}")
 
 
-def serve_viterbi(args):
+def viterbi_service(args):
+    """The ``--service viterbi`` path up to the first decode: (run, src),
+    where ``run(llrs) -> bits`` decodes one batch in the selected
+    ``--mode`` and ``src`` is the seeded ``ChannelStream`` that feeds it
+    (``src.batch_at(i) -> (bits, llrs)``)."""
     import dataclasses
 
     from repro.codes.registry import get_code
@@ -133,6 +137,11 @@ def serve_viterbi(args):
         stream_len=args.stream_len, ebn0_db=args.ebn0,
         code=args.code,
     )
+    return run, src
+
+
+def serve_viterbi(args):
+    run, src = viterbi_service(args)
     bits, llrs = src.batch_at(0)
     run(llrs).block_until_ready()  # compile
     total = err = 0
@@ -323,7 +332,7 @@ def serve_lm(args):
     )
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--service", default="viterbi",
                     choices=["viterbi", "engine", "lm"])
@@ -394,7 +403,14 @@ def main():
         "file and print the Prometheus text dump on drain; view with "
         "python -m repro.obs.top --jsonl PATH",
     )
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.service == "viterbi":
         serve_viterbi(args)
     elif args.service == "engine":

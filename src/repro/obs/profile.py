@@ -17,12 +17,14 @@ per-dispatch record the span layer can attach:
     ``3*tile + log2(tiles)`` for the time-parallel scan.  The modeled
     depth mirrors what ``hlocount.total_trip_count`` reports on the
     lowered HLO (asserted in tests on a small shape).
-  * **roofline terms** — ``roofline.TPU_V5E`` by default:
+  * **roofline terms** — the peaks of the device the process runs on,
+    from ``roofline.PEAKS_BY_DEVICE_KIND`` (keyed by ``device_kind``):
     ``t_compute = flops/peak``, ``t_memory = bytes/bw``, the bottleneck
     label, and arithmetic intensity; ``achieved(wall)`` turns a measured
-    dispatch wall time into achieved-vs-peak fractions (honest caveat:
-    on the CPU dev host the "achieved" fraction prices CPU wall against
-    the v5e roof — a cross-PR trend signal, not a utilization claim).
+    dispatch wall time into achieved rates, and into fractions of peak
+    only where the device has peaks.  A device not in the table (a CPU
+    host) gets no roofline terms and no fractions — never another
+    chip's numbers.
 
 Everything is pure shape arithmetic; profiles are cached per
 (spec, path, cell) so the per-dispatch cost when tracing is enabled is
@@ -38,7 +40,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.trellis import CodeSpec, build_acs_tables
-from repro.roofline import HW, TPU_V5E
+from repro.roofline import HW, hw_for_device_kind
 
 __all__ = ["DispatchProfile", "dispatch_profile", "measured_depth"]
 
@@ -59,9 +61,7 @@ class DispatchProfile:
     hbm_bytes: int        # static interface bytes (traffic.py rules)
     flops: float          # fused-ACS matmul model (2*T'*F*S*(B+S) core)
     depth: int            # modeled sequential trip count (hlocount rules)
-    hw_name: str = TPU_V5E.name
-    peak_flops: float = TPU_V5E.peak_flops
-    hbm_bw: float = TPU_V5E.hbm_bw
+    hw: Optional[HW] = None  # peaks of the device; None = not in the table
 
     @property
     def intensity(self) -> float:
@@ -69,46 +69,53 @@ class DispatchProfile:
         return self.flops / self.hbm_bytes if self.hbm_bytes else 0.0
 
     @property
-    def t_compute(self) -> float:
-        return self.flops / self.peak_flops
+    def t_compute(self) -> Optional[float]:
+        return None if self.hw is None else self.flops / self.hw.peak_flops
 
     @property
-    def t_memory(self) -> float:
-        return self.hbm_bytes / self.hbm_bw
+    def t_memory(self) -> Optional[float]:
+        return None if self.hw is None else self.hbm_bytes / self.hw.hbm_bw
 
     @property
-    def bottleneck(self) -> str:
+    def bottleneck(self) -> Optional[str]:
+        if self.hw is None:
+            return None
         return "compute" if self.t_compute >= self.t_memory else "memory"
 
     def span_attrs(self) -> dict:
         """The per-dispatch attributes the engine attaches to its
-        dispatch spans (flat, JSON-able)."""
-        return {
+        dispatch spans (flat, JSON-able); the roofline terms only where
+        the device has peaks."""
+        attrs = {
             "hbm_bytes_modeled": int(self.hbm_bytes),
             "flops_modeled": float(self.flops),
             "intensity": round(self.intensity, 4),
             "depth_modeled": int(self.depth),
-            "t_memory_us": round(self.t_memory * 1e6, 3),
-            "t_compute_us": round(self.t_compute * 1e6, 3),
-            "bottleneck": self.bottleneck,
-            "hw": self.hw_name,
         }
+        if self.hw is not None:
+            attrs.update(
+                t_memory_us=round(self.t_memory * 1e6, 3),
+                t_compute_us=round(self.t_compute * 1e6, 3),
+                bottleneck=self.bottleneck,
+                hw=self.hw.name,
+            )
+        return attrs
 
     def achieved(self, wall_s: float, n_devices: int = 1) -> dict:
-        """Achieved-vs-peak at a measured dispatch wall time: the
-        roofline.py fold (module docstring caveat about CPU hosts)."""
+        """Achieved rates at a measured dispatch wall time, and their
+        fractions of peak where the device has peaks."""
         if wall_s <= 0:
             return {}
         dev = max(n_devices, 1)
         bw = self.hbm_bytes / wall_s / dev
         fl = self.flops / wall_s / dev
-        return {
-            "wall_s": wall_s,
-            "achieved_hbm_Bps": bw,
-            "achieved_hbm_frac": bw / self.hbm_bw,
-            "achieved_flops": fl,
-            "achieved_flops_frac": fl / self.peak_flops,
-        }
+        out = {"wall_s": wall_s, "achieved_hbm_Bps": bw, "achieved_flops": fl}
+        if self.hw is not None:
+            out.update(
+                achieved_hbm_frac=bw / self.hw.hbm_bw,
+                achieved_flops_frac=fl / self.hw.peak_flops,
+            )
+        return out
 
 
 def _two_pass_batch_bytes(T, F, S, R, B, W_bytes, mm) -> int:
@@ -137,7 +144,7 @@ def _profile_key(dec, path: str, f_cell: int, n_stages: int):
 def _profile_cached(
     spec: CodeSpec, rho: int, path: str, f_cell: int, n_stages: int,
     decision_depth: int, packed: bool, mm: int,
-    transfer_tile: Optional[int], hw: HW,
+    transfer_tile: Optional[int], hw: Optional[HW],
 ) -> DispatchProfile:
     from repro.core.kernel_geometry import pick_transfer_tile
     from repro.kernels.viterbi_acs import ring_dtype, ring_words
@@ -194,20 +201,30 @@ def _profile_cached(
         depth = 2 * T  # forward scan + traceback scan
     return DispatchProfile(
         path=path, f_cell=F, n_stages=int(n_stages),
-        hbm_bytes=int(bytes_), flops=float(flops), depth=int(depth),
-        hw_name=hw.name, peak_flops=hw.peak_flops, hbm_bw=hw.hbm_bw,
+        hbm_bytes=int(bytes_), flops=float(flops), depth=int(depth), hw=hw,
     )
 
 
 def dispatch_profile(dec, path: str, f_cell: int, n_stages: int,
-                     hw: HW = TPU_V5E) -> DispatchProfile:
+                     hw: Optional[HW] = None) -> DispatchProfile:
     """Profile of dispatching ``f_cell`` frames x ``n_stages`` stages of
     ``dec``'s code down the named route.  ``dec`` is a
     ``core.decoder.ViterbiDecoder``; unknown paths fall back to the
-    dense-batch model (the engine's default route)."""
+    dense-batch model (the engine's default route).  ``hw`` defaults to
+    the peak-table entry of the process's first device (None when that
+    device kind is not in the table)."""
     if path not in _PATHS:
         path = "batch"
+    if hw is None:
+        hw = _device_hw()
     return _profile_cached(*_profile_key(dec, path, f_cell, n_stages), hw)
+
+
+@functools.lru_cache(maxsize=1)
+def _device_hw() -> Optional[HW]:
+    import jax
+
+    return hw_for_device_kind(jax.devices()[0].device_kind)
 
 
 def measured_depth(fn, *avals) -> int:

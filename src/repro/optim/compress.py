@@ -63,7 +63,6 @@ def make_dp_train_step_compressed(loss_fn, mesh, axis_name="data",
     step(params, err, batch) -> (params, err, loss) where ``err`` is the
     error-feedback residual pytree (same shapes as params).
     """
-    from jax.experimental.shard_map import shard_map
 
     def local_step(params, err, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -85,10 +84,10 @@ def make_dp_train_step_compressed(loss_fn, mesh, axis_name="data",
 
     pspec = P()  # replicated params/err
     bspec = P(axis_name)
-    return shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(pspec, pspec, bspec),
         out_specs=(pspec, pspec, pspec),
-        check_rep=False,
+        check_vma=False,
     )

@@ -23,6 +23,8 @@ from typing import Dict, List, Optional
 __all__ = [
     "HW",
     "TPU_V5E",
+    "PEAKS_BY_DEVICE_KIND",
+    "hw_for_device_kind",
     "CollectiveOp",
     "parse_collectives",
     "collective_wire_bytes",
@@ -42,6 +44,19 @@ class HW:
 TPU_V5E = HW(
     name="tpu-v5e", peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9
 )
+
+# published per-chip peaks keyed by ``jax.Device.device_kind`` (TPU v5e:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s
+# HBM).  A device kind not listed here has no peaks: nothing may price
+# its measurements against another chip's roof.
+PEAKS_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,  # what a v5e reports as its device_kind
+}
+
+
+def hw_for_device_kind(kind: str) -> Optional[HW]:
+    """The peak table entry of a device kind, or None when unlisted."""
+    return PEAKS_BY_DEVICE_KIND.get(kind)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

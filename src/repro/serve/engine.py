@@ -95,7 +95,7 @@ from repro.core.kernel_geometry import (
 from repro.core.validate import InvalidInputError, validate_llrs
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import NullRecorder, SpanRecorder
-from repro.runtime.chaos import DeviceFailure, DispatchTimeout
+from repro.runtime.chaos import DeviceFailure, DispatchTimeout, InjectedFault
 from repro.runtime.failure import QuarantineRecord, RetryPolicy
 from repro.verify.scrub import SdcScrubber
 
@@ -372,6 +372,10 @@ class DecodeEngine:
             maxlen=1024
         )
         self._done_buffer: List[Ticket] = []  # completed out of band
+        # text of the most recent untyped dispatch errors (stats())
+        self.error_log: "collections.deque[str]" = collections.deque(
+            maxlen=64
+        )
         # §12 accounting: every counter lives in the registry (stats()
         # reads it back), spans go through the recorder (no-op default)
         self.registry = registry if registry is not None else MetricsRegistry()
@@ -929,15 +933,20 @@ class DecodeEngine:
         (the cell's LLRs are immutable and no engine state was updated
         yet), and every ladder rung is bit-identical by the §10 routing
         contract — so a retried or degraded dispatch emits exactly the
-        bits the first attempt would have."""
+        bits the first attempt would have.
+
+        Only the typed faults (``InjectedFault``: device failures,
+        dispatch timeouts, transient compile errors) are retried or
+        degraded; any other exception is recorded and re-raised at once,
+        and its riders fail with a typed ``decode_failed`` error."""
         ladder = DEGRADATION_LADDER.get(path, (path,))
         rung, attempt, retries = 0, 0, 0
         while True:
             try:
                 self._inject(code, path)
                 return path, fn(arr), retries
-            except Exception as e:  # noqa: BLE001 — classify below
-                kind = getattr(e, "kind", "error")
+            except InjectedFault as e:
+                kind = e.kind
                 if kind != "slow":  # slow already counted by _inject
                     self._m_faults.inc(1, kind=kind, path=path)
                 self.recorder.event(
@@ -984,6 +993,26 @@ class DecodeEngine:
                     continue
                 e.engine_retries = retries  # rides to _fail_tickets
                 raise
+            except Exception as e:
+                # a real error (a Mosaic compile failure, a shape bug) is
+                # never retried or degraded past: that would hide it
+                # behind a slower rung and end the run green
+                self._record_error(e, path, retries, now, dsp)
+                raise
+
+    def _record_error(self, e: Exception, path: str, retries: int,
+                      now: float, dsp) -> None:
+        """Account an untyped dispatch exception: counted as a fault of
+        kind "error", its text kept in ``error_log`` (``stats()``), and
+        the riders failed by the caller with a typed error."""
+        self._m_faults.inc(1, kind="error", path=path)
+        self.error_log.append(f"{path}: {type(e).__name__}: {e}")
+        self.recorder.event(
+            "engine.fault", kind="error", path=path, error=str(e), now=now
+        )
+        if dsp is not None:
+            dsp.set(fault="error")
+        e.engine_retries = retries
 
     # -- online SDC scrubbing (DESIGN.md §14) -----------------------------
 
@@ -1290,8 +1319,8 @@ class DecodeEngine:
                         self._inject(code_name, "session")
                         new_states, outs = self._fns[key](states, chunks)
                         break
-                    except Exception as e:  # noqa: BLE001 — §13 guard
-                        kind = getattr(e, "kind", "error")
+                    except InjectedFault as e:
+                        kind = e.kind
                         if kind != "slow":
                             self._m_faults.inc(1, kind=kind, path="session")
                         self.recorder.event(
@@ -1315,6 +1344,13 @@ class DecodeEngine:
                         return self._session_dispatch_failed(
                             sessions, tickets, chunks, e, now,
                             abandon_on_failure,
+                        ), False
+                    except Exception as e:
+                        # untyped: fail the chunks now — requeueing a
+                        # deterministic error would only repeat it
+                        self._record_error(e, "session", retries, now, dsp)
+                        return self._session_dispatch_failed(
+                            sessions, tickets, chunks, e, now, True,
                         ), False
                 with rec.span("engine.device_wait"):
                     outs = [np.asarray(o) for o in outs]
@@ -1599,6 +1635,7 @@ class DecodeEngine:
             "failovers": int(self._m_failover.total()),
             "expired": int(self._m_requests.total(event="expired")),
             "failed": int(self._m_requests.total(event="failed")),
+            "errors": list(self.error_log),
             "checkpoints": int(self._m_ckpt.total()),
             # §14 data-integrity block (additive; zero/empty when the
             # scrubber is disabled and inputs are clean)

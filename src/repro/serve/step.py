@@ -3,11 +3,6 @@ stream-decode service (DESIGN.md §6), and the multi-tenant
 ``DecodeEngine`` factory (DESIGN.md §10)."""
 from __future__ import annotations
 
-import functools
-
-import jax
-import jax.numpy as jnp
-
 from repro.configs.base import ArchConfig
 from repro.models import lm
 
@@ -72,7 +67,8 @@ def make_viterbi_serve_step(vcfg, precision=None, use_kernel: bool = False,
     llrs: (n_streams, stream_len, beta) -> bits (n_streams, stream_len).
 
     mode="tiled": frame tiling turns each stream into stream_len/frame_len
-    independent windows; vmap adds the stream batch — all of it pure data
+    independent windows, and the windows of every stream form one frame
+    batch — all of it pure data
     parallelism (the paper's §III parallelization), sharded over every
     mesh axis.  With ``use_kernel=True`` the windows decode through the
     one-pass time-tiled ACS+traceback kernel (DESIGN.md §8): survivors
@@ -106,8 +102,7 @@ def make_viterbi_serve_step(vcfg, precision=None, use_kernel: bool = False,
         cfg = decoder.default_tiled_config(vcfg.tiled)
 
         def serve_step(llrs):
-            fn = functools.partial(decoder.decode_stream_tiled, cfg=cfg)
-            return jax.vmap(fn)(llrs)
+            return decoder.decode_streams_tiled(llrs, cfg=cfg)
     elif mode == "batch":
         if decoder.termination == "tailbiting":
             def serve_step(llrs):

@@ -43,7 +43,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.codes.registry import StandardCode, get_code
@@ -283,10 +282,10 @@ class BerFarm:
             out = np.asarray(jax.jit(local)(keys))
         else:
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     local, mesh=self.mesh,
                     in_specs=P(self.axis), out_specs=P(self.axis),
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
             out = np.asarray(fn(keys))

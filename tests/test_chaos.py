@@ -476,6 +476,38 @@ def test_degrade_stream_to_xla_chunked(monkeypatch):
     np.testing.assert_array_equal(t.bits, bits)
 
 
+def test_untyped_dispatch_error_is_not_retried_or_degraded(monkeypatch):
+    """A real error from a dispatch (here a stand-in for a Mosaic
+    compile failure) is never retried or degraded past — a slower rung
+    would hide it: the riders fail with a typed error, the fault is
+    counted, and its text is kept in stats()."""
+    from repro.serve import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "STREAM_MIN_STEPS", 8)
+    engine = DecodeEngine(use_kernel=True, retry=3, decision_depth=DEPTH)
+
+    def broken(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    dec = engine._decoder("ccsds-k7")
+    monkeypatch.setattr(dec, "decode_stream_chunked", broken)
+    _, req = _request("ccsds-k7", 256, "throughput", seed=12)
+    t = engine.submit(req, now=0.0)
+    engine.drain(now=0.0)
+    s = engine.stats()
+    assert t.error == "decode_failed:RuntimeError" and t.path is None
+    assert s["retries"] == 0 and s["degraded"] == 0
+    assert s["faults"] == {"error": 1} and s["failed"] == 1
+    assert s["errors"] == [
+        "stream: RuntimeError: Mosaic failed to compile TPU kernel"
+    ]
+    # the engine keeps serving other cells (latency class: batch path)
+    bits, req2 = _request("ccsds-k7", 70, "latency", seed=13)
+    t2 = engine.submit(req2, now=1.0)
+    engine.drain(now=1.0)
+    np.testing.assert_array_equal(t2.bits, bits)
+
+
 def test_heartbeat_driven_failover():
     """Hosts silent past the monitor timeout are treated as failed
     devices at the top of poll: the mesh re-plans without waiting for a

@@ -124,10 +124,12 @@ def test_one_pass_engages_and_ring_is_packed():
     assert state.hist.dtype == jnp.int32
     assert state.hist.shape[-1] == SPEC.n_states // 16
     assert dec._one_pass_tile(128, state.depth_steps) == 32
-    # a ring beyond the VMEM budget falls back to two-pass
-    big = ViterbiDecoder(SPEC, use_kernel=True, decision_depth=5120)
-    big.ring_packed = False  # unpacked 5120-stage ring: > VMEM budget
-    assert big._one_pass_tile(2048, 2560) is None
+    # the default depth's packed ring fits; a ring beyond the VMEM
+    # budget falls back to two-pass
+    big = ViterbiDecoder(SPEC, use_kernel=True, decision_depth=20480)
+    assert big._one_pass_tile(2048, 2560) == 32
+    big.ring_packed = False  # unpacked 20480-stage ring: > VMEM budget
+    assert big._one_pass_tile(2048, 10240) is None
 
 
 def test_one_pass_packed_unpacked_ring_parity():
@@ -230,3 +232,42 @@ def test_one_pass_streaming_traffic_gate():
         rep["one_pass"]["kernel_bytes"] * 2
         < rep["two_pass"]["kernel_bytes"]
     ), rep
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,nbytes",
+    [
+        # Mosaic's own allocation report for the pre-layout ring
+        ((2592, 256, 4), jnp.int32, 339_738_624),
+        # frames on lanes: the (D+TT, W, BF) packed ring is unpadded
+        ((2592, 4, 256), jnp.int32, 2592 * 4 * 256 * 4),
+        ((100, 3, 256), jnp.int32, 100 * 4 * 256 * 4),
+        ((100, 5, 64), jnp.int32, 100 * 8 * 128 * 4),
+        ((100, 1, 256), jnp.bfloat16, 100 * 2 * 256 * 2),
+        ((100, 2, 256), jnp.int8, 100 * 4 * 256),
+        ((100, 12, 256), jnp.int8, 100 * 16 * 256),
+        ((2592, 64, 256), jnp.int8, 2592 * 64 * 256),
+        ((256, 4), jnp.float32, 256 * 128 * 4),
+    ],
+)
+def test_vmem_bytes_counts_mosaic_layout(shape, dtype, nbytes):
+    """VMEM accounting counts bytes as Mosaic lays them out (the
+    tilings Mosaic reported for these shapes when they overflowed)."""
+    from repro.core.kernel_geometry import vmem_bytes
+
+    assert vmem_bytes(shape, dtype) == nbytes
+
+
+def test_one_pass_guard_counts_whole_kernel():
+    """The one-pass guard budgets the whole kernel, ring included: the
+    default depth's ring fits, a 16x deeper one does not."""
+    from repro.core.kernel_geometry import (
+        KERNEL_VMEM_BUDGET, fused_decode_vmem_bytes, fused_ring_vmem_bytes,
+    )
+
+    ring = fused_ring_vmem_bytes(2560, 32, 256, 64, True)
+    total = fused_decode_vmem_bytes(2560, 32, 256, 64, 4, 4, True)
+    assert ring == 2592 * 4 * 256 * 4 < total < KERNEL_VMEM_BUDGET
+    assert fused_decode_vmem_bytes(40960, 32, 256, 64, 4, 4, True) > (
+        KERNEL_VMEM_BUDGET
+    )

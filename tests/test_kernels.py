@@ -76,6 +76,21 @@ def test_kernel_frame_padding():
         np.testing.assert_array_equal(phi_r, phi_k)
 
 
+@pytest.mark.parametrize("n_stages", [256, 600, 1030])
+@pytest.mark.parametrize("pack", [False, True], ids=["i8", "packed"])
+def test_kernel_time_grid_matches_ref(n_stages, pack):
+    """The two-pass kernel's time grid (128-step tiles, carry in VMEM
+    scratch): one full tile, and ragged last tiles whose padded steps
+    must leave the carry untouched."""
+    lam_r, phi_r, lam_k, phi_k = _run_both(
+        SPECS["k7"], 2, 5, n_stages, seed=n_stages, pack_survivors=pack
+    )
+    np.testing.assert_array_equal(np.asarray(lam_r), np.asarray(lam_k))
+    if pack:
+        phi_k = unpack_survivors(phi_k, 64, 4)
+    np.testing.assert_array_equal(phi_r, phi_k)
+
+
 def test_kernel_survivor_packing_roundtrip():
     """pack_survivors returns the PACKED (T, F, S//16) int32 words —
     eager unpacking would re-materialize exactly the tensor packing

@@ -176,19 +176,33 @@ def test_dispatch_profile_attrs_and_achieved():
     from repro.core.decoder import ViterbiDecoder
     from repro.obs.profile import dispatch_profile
 
+    from repro.roofline import PEAKS_BY_DEVICE_KIND, TPU_V5E
+
     dec = ViterbiDecoder.from_standard("ccsds-k7")
-    prof = dispatch_profile(dec, "batch", f_cell=32, n_stages=256)
+    prof = dispatch_profile(
+        dec, "batch", f_cell=32, n_stages=256,
+        hw=PEAKS_BY_DEVICE_KIND["TPU v5 lite"],
+    )
     attrs = prof.span_attrs()
     for key in ("hbm_bytes_modeled", "flops_modeled", "depth_modeled",
                 "intensity", "t_memory_us", "t_compute_us", "bottleneck"):
         assert key in attrs, key
     assert attrs["hbm_bytes_modeled"] > 0 and attrs["depth_modeled"] > 0
+    assert attrs["hw"] == TPU_V5E.name
     # 1 s wall for a tiny cell: far off the v5e roofline but nonzero
     ach = prof.achieved(wall_s=1.0)
     assert 0.0 < ach["achieved_hbm_frac"] < 1.0
     assert 0.0 < ach["achieved_flops_frac"] < 1.0
     # lru cache: same cell -> same object, no traffic recomputation
-    assert dispatch_profile(dec, "batch", 32, 256) is prof
+    assert dispatch_profile(dec, "batch", 32, 256, hw=TPU_V5E) is prof
+    # a device kind without peaks (this CPU host) gets modeled work and
+    # achieved rates, but no roofline terms and no fractions of peak
+    host = dispatch_profile(dec, "batch", 32, 256)
+    assert host.hw is None and host.hbm_bytes == prof.hbm_bytes
+    assert "t_compute_us" not in host.span_attrs()
+    ach = host.achieved(wall_s=1.0)
+    assert ach["achieved_hbm_Bps"] > 0
+    assert not any(k.endswith("_frac") for k in ach)
 
 
 def test_measured_depth_counts_scan_trips():
