@@ -37,6 +37,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.obs.metrics import default_registry
+from repro.obs.trace import NullRecorder
 
 from .trellis import AcsTables, CodeSpec, build_acs_tables
 from .validate import (
@@ -77,17 +81,11 @@ __all__ = [
 DEFAULT_DECISION_DEPTH = 5120
 
 
-def _count_dispatch(path: str) -> None:
-    """§12 path-selection counter, written to the library-wide default
-    registry (a zero-cost ``NullRegistry`` until observability installs
-    a real one).  Called at host-side dispatch boundaries only — never
-    from inside a jitted function."""
-    from repro.obs.metrics import default_registry
-
-    default_registry().counter(
-        "decoder_dispatch_total",
-        "ViterbiDecoder dispatches by selected decode path",
-    ).inc(1, path=path)
+def _host_arrays(xs) -> Tuple[int, int]:
+    """(count, bytes) of the arrays in ``xs`` that live on the host:
+    each is one host-to-device copy when handed to ``jnp``."""
+    host = [np.asarray(x) for x in xs if not isinstance(x, jax.Array)]
+    return len(host), sum(x.nbytes for x in host)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,6 +213,13 @@ class ViterbiDecoder:
     tables are built eagerly and every entry point reuses the same jitted
     computations (tables are hashed by identity, so one decoder instance
     never re-traces for a second call of the same shape).
+
+    ``recorder`` (an ``obs.SpanRecorder``; default the no-op
+    ``NullRecorder``) receives the host-side ``decoder.*`` spans of
+    ``decode_batch`` and ``decode_chunk_multi`` (DESIGN.md §12);
+    ``registry`` receives ``decoder_dispatch_total{path}`` — None
+    means the process default, ``obs.default_registry()``, looked up at
+    each dispatch so ``set_default_registry`` keeps working.
     """
 
     def __init__(
@@ -234,6 +239,8 @@ class ViterbiDecoder:
         transfer_tile: Optional[int] = None,
         validate_inputs: bool = True,
         sanitize: bool = False,
+        recorder=None,
+        registry=None,
     ):
         if decision_depth % rho:
             raise ValueError(
@@ -298,6 +305,19 @@ class ViterbiDecoder:
             RenormGuard.for_precision(self.precision)
             if (validate_inputs and not self.precision.renorm) else None
         )
+        self.recorder = recorder if recorder is not None else NullRecorder()
+        self.registry = registry
+
+    def _count_dispatch(self, path: str) -> None:
+        """§12 path-selection counter.  Called at host-side dispatch
+        boundaries only — never from inside a jitted function."""
+        reg = self.registry if self.registry is not None else (
+            default_registry()
+        )
+        reg.counter(
+            "decoder_dispatch_total",
+            "ViterbiDecoder dispatches by selected decode path",
+        ).inc(1, path=path)
 
     @classmethod
     def from_standard(
@@ -315,6 +335,8 @@ class ViterbiDecoder:
         transfer_tile: Optional[int] = None,
         validate_inputs: bool = True,
         sanitize: bool = False,
+        recorder=None,
+        registry=None,
     ) -> "ViterbiDecoder":
         """One front door for every deployed standard (DESIGN.md §7):
         resolves a ``repro.codes.registry`` entry — mother code, puncture
@@ -340,6 +362,8 @@ class ViterbiDecoder:
             transfer_tile=transfer_tile,
             validate_inputs=validate_inputs,
             sanitize=sanitize,
+            recorder=recorder,
+            registry=registry,
         )
 
     @classmethod
@@ -460,59 +484,70 @@ class ViterbiDecoder:
         large-T serving) or on request.
         """
         term = termination or self.termination
-        llrs = self.depunctured(llrs)
+        rec = self.recorder
+        with rec.span("decoder.depuncture") as sp:
+            if rec.enabled:
+                n_h2d, h2d_bytes = _host_arrays([llrs])
+                sp.set(h2d_arrays=n_h2d, h2d_bytes=h2d_bytes)
+            llrs = self.depunctured(llrs)
         if term == "tailbiting":
             return self.decode_tailbiting(
                 llrs, time_parallel=time_parallel
             )[0]
-        llrs = self._harden(llrs)
-        F, n, _ = llrs.shape
-        if self.validate_inputs and not self.precision.renorm:
-            batch_headroom_check(
-                self.precision,
-                -(-n // self.rho),
-                float(jnp.max(jnp.abs(llrs))) if n else 0.0,
-                self.rho,
-                llrs.shape[2],
-            )
-        pad = (-n) % self.rho
-        if pad:
-            if final_state is not None:
-                raise ValueError(
-                    f"final_state requires n divisible by rho={self.rho}; "
-                    f"got n={n} (the pin would land on padded stages)"
+        # both checks read the LLRs back: device round trips
+        with rec.span("decoder.validate"):
+            llrs = self._harden(llrs)
+            F, n, _ = llrs.shape
+            if self.validate_inputs and not self.precision.renorm:
+                batch_headroom_check(
+                    self.precision,
+                    -(-n // self.rho),
+                    float(jnp.max(jnp.abs(llrs))) if n else 0.0,
+                    self.rho,
+                    llrs.shape[2],
                 )
-            llrs = jnp.pad(llrs, ((0, 0), (0, pad), (0, 0)))
-        tp_tile = self._time_parallel_tile(
-            F, (n + pad) // self.rho, time_parallel
-        )
-        _count_dispatch("time_parallel" if tp_tile is not None else "batch")
-        if tp_tile is not None:
-            from .timeparallel import decode_time_parallel
+        with rec.span("decoder.launch"):
+            pad = (-n) % self.rho
+            if pad:
+                if final_state is not None:
+                    raise ValueError(
+                        f"final_state requires n divisible by "
+                        f"rho={self.rho}; got n={n} (the pin would land "
+                        f"on padded stages)"
+                    )
+                llrs = jnp.pad(llrs, ((0, 0), (0, pad), (0, 0)))
+            tp_tile = self._time_parallel_tile(
+                F, (n + pad) // self.rho, time_parallel
+            )
+            self._count_dispatch(
+                "time_parallel" if tp_tile is not None else "batch"
+            )
+            if tp_tile is not None:
+                from .timeparallel import decode_time_parallel
 
-            out = decode_time_parallel(
-                llrs,
-                self.spec,
-                rho=self.rho,
-                initial_state=initial_state,
-                final_state=final_state,
-                precision=self.precision,
-                transfer_tile=tp_tile,
-                use_kernel=self.use_kernel,
-                pack_survivors=self.pack_survivors,
-            )
-        else:
-            out = decode_frames(
-                llrs,
-                self.spec,
-                rho=self.rho,
-                initial_state=initial_state,
-                final_state=final_state,
-                precision=self.precision,
-                use_kernel=self.use_kernel,
-                pack_survivors=self.pack_survivors,
-            )
-        return out[:, :n] if pad else out
+                out = decode_time_parallel(
+                    llrs,
+                    self.spec,
+                    rho=self.rho,
+                    initial_state=initial_state,
+                    final_state=final_state,
+                    precision=self.precision,
+                    transfer_tile=tp_tile,
+                    use_kernel=self.use_kernel,
+                    pack_survivors=self.pack_survivors,
+                )
+            else:
+                out = decode_frames(
+                    llrs,
+                    self.spec,
+                    rho=self.rho,
+                    initial_state=initial_state,
+                    final_state=final_state,
+                    precision=self.precision,
+                    use_kernel=self.use_kernel,
+                    pack_survivors=self.pack_survivors,
+                )
+            return out[:, :n] if pad else out
 
     def decode_tailbiting(
         self,
@@ -538,7 +573,7 @@ class ViterbiDecoder:
         tp_tile = self._time_parallel_tile(
             F, n // tables.rho, time_parallel
         )
-        _count_dispatch("wava")
+        self._count_dispatch("wava")
         return wava_decode(
             llrs,
             tables,
@@ -603,14 +638,14 @@ class ViterbiDecoder:
             if output == "list":
                 from .soft import wava_list_decode
 
-                _count_dispatch("soft_list")
+                self._count_dispatch("soft_list")
                 bits, metrics, _ = wava_list_decode(
                     llrs, tables, n_list, self.precision
                 )
                 return bits, metrics
             from .soft import bcjr_circular_llrs
 
-            _count_dispatch("soft")
+            self._count_dispatch("soft")
             out = bcjr_circular_llrs(
                 llrs, tables, self.precision, use_kernel=self.use_kernel
             )
@@ -626,7 +661,7 @@ class ViterbiDecoder:
         if output == "list":
             from .soft import list_decode
 
-            _count_dispatch("soft_list")
+            self._count_dispatch("soft_list")
             bits, metrics = list_decode(
                 llrs,
                 self.spec,
@@ -639,7 +674,7 @@ class ViterbiDecoder:
             return (bits[:, :, :n] if pad else bits), metrics
         from .soft import bcjr_llrs
 
-        _count_dispatch("soft")
+        self._count_dispatch("soft")
         out = bcjr_llrs(
             llrs,
             self.spec,
@@ -705,7 +740,7 @@ class ViterbiDecoder:
         cfg = cfg or self.default_tiled_config()
         if cfg.rho != self.rho:
             raise ValueError(f"cfg.rho={cfg.rho} != decoder rho={self.rho}")
-        _count_dispatch("tiled")
+        self._count_dispatch("tiled")
         return tiled_decode_streams(
             llrs,
             self.spec,
@@ -818,7 +853,7 @@ class ViterbiDecoder:
         dispatch point under ``decode_chunk`` and the engine's fused
         multi-session step (``decode_chunk_multi``, DESIGN.md §10)."""
         tt = self._one_pass_tile(blocks.shape[0], hist.shape[0])
-        _count_dispatch("chunk_one_pass" if tt else "chunk_two_pass")
+        self._count_dispatch("chunk_one_pass" if tt else "chunk_two_pass")
         if tt:
             return _chunk_step_fused(
                 hist,
@@ -865,43 +900,51 @@ class ViterbiDecoder:
         depths = {s.depth_steps for s in states}
         if len(depths) != 1:
             raise ValueError(f"mixed decision depths {sorted(depths)}")
-        chunks = [jnp.asarray(ch) for ch in chunks]
-        steps = {ch.shape[1] for ch in chunks}
-        if len(steps) != 1:
-            raise ValueError(f"mixed chunk lengths {sorted(steps)}")
-        for s, ch in zip(states, chunks):
-            if ch.shape[0] != s.n_frames:
-                raise ValueError(
-                    f"state has {s.n_frames} frames, chunk {ch.shape[0]}"
-                )
-        stacked = self._harden(
-            jnp.concatenate(chunks, axis=0), where="stream"
-        )
-        blocks = blocks_from_llrs(stacked, self.rho)
-        hist = jnp.concatenate([s.hist for s in states], axis=1)
-        lam = jnp.concatenate([s.lam for s in states], axis=0)
-        hist2, lam2, bits = self._dispatch_chunk(hist, lam, blocks)
+        rec = self.recorder
+        with rec.span("decoder.stack") as sp:
+            if rec.enabled:
+                n_h2d, h2d_bytes = _host_arrays(chunks)
+                sp.set(h2d_arrays=n_h2d, h2d_bytes=h2d_bytes)
+            chunks = [jnp.asarray(ch) for ch in chunks]
+            steps = {ch.shape[1] for ch in chunks}
+            if len(steps) != 1:
+                raise ValueError(f"mixed chunk lengths {sorted(steps)}")
+            for s, ch in zip(states, chunks):
+                if ch.shape[0] != s.n_frames:
+                    raise ValueError(
+                        f"state has {s.n_frames} frames, chunk "
+                        f"{ch.shape[0]}"
+                    )
+            stacked = jnp.concatenate(chunks, axis=0)
+            hist = jnp.concatenate([s.hist for s in states], axis=1)
+            lam = jnp.concatenate([s.lam for s in states], axis=0)
+        with rec.span("decoder.validate"):
+            stacked = self._harden(stacked, where="stream")
+        with rec.span("decoder.launch"):
+            blocks = blocks_from_llrs(stacked, self.rho)
+            hist2, lam2, bits = self._dispatch_chunk(hist, lam, blocks)
         T = steps.pop() // self.rho
         D = depths.pop()
         if self.renorm_guard is not None and any(
                 self.renorm_guard.due(s.pos + T, T) for s in states):
             lam2, _ = self.renorm_guard.observe(lam2, t_chunk=T)
         new_states, outs, off = [], [], 0
-        for s in states:
-            f = s.n_frames
-            b = bits[off : off + f]
-            n_valid = _window_valid(s.pos, T, D)
-            outs.append(
-                b[:, (T - n_valid) * self.rho:] if n_valid else b[:, :0]
-            )
-            new_states.append(
-                StreamState(
-                    lam=lam2[off : off + f],
-                    hist=hist2[:, off : off + f],
-                    pos=s.pos + T,
+        with rec.span("decoder.split"):
+            for s in states:
+                f = s.n_frames
+                b = bits[off : off + f]
+                n_valid = _window_valid(s.pos, T, D)
+                outs.append(
+                    b[:, (T - n_valid) * self.rho:] if n_valid else b[:, :0]
                 )
-            )
-            off += f
+                new_states.append(
+                    StreamState(
+                        lam=lam2[off : off + f],
+                        hist=hist2[:, off : off + f],
+                        pos=s.pos + T,
+                    )
+                )
+                off += f
         return new_states, outs
 
     def flush_stream(
@@ -988,7 +1031,7 @@ class ViterbiDecoder:
                 "sharded tail-biting decode not implemented; shard "
                 "frames manually over decode_tailbiting"
             )
-        _count_dispatch("sharded")
+        self._count_dispatch("sharded")
         return sharded_decode_frames(
             self._harden(self.depunctured(llrs)),
             self.spec,

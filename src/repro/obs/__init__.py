@@ -1,8 +1,8 @@
-"""Observability subsystem (DESIGN.md §12): metrics registry, span
-tracing, and the device-profile adapter — dependency-free, zero-cost
-when disabled.
+"""Observability subsystem (DESIGN.md §12): metrics registry and span
+tracing mirrored into the JAX profiler's trace — dependency-free,
+zero-cost when disabled.
 
-Three layers, composable but independently usable:
+Two layers, composable but independently usable:
 
   * :mod:`repro.obs.metrics` — ``MetricsRegistry`` of counters, gauges
     and fixed power-of-two-bucket histograms keyed by the serving
@@ -10,9 +10,8 @@ Three layers, composable but independently usable:
     text and plain-dict snapshot exporters.
   * :mod:`repro.obs.trace` — ``SpanRecorder``/``span(...)`` nested span
     layer with a JSONL event-log sink (``experiments/obs/`` by
-    convention).
-  * :mod:`repro.obs.profile` — per-dispatch modeled HBM bytes / flops /
-    trip-count depth / roofline terms folded into span attributes.
+    convention), each span also a ``jax.profiler.TraceAnnotation`` so a
+    profiler trace puts the host's spans beside the device ops.
 
 ``Observability`` bundles one registry + one recorder (+ optional JSONL
 sink) for handing to ``DecodeEngine``/``BerFarm``; the module-level
@@ -55,7 +54,6 @@ from repro.obs.metrics import (
     default_registry,
     set_default_registry,
 )
-from repro.obs.profile import DispatchProfile, dispatch_profile, measured_depth
 from repro.obs.trace import JsonlSink, NullRecorder, Span, SpanRecorder
 
 __all__ = [
@@ -67,9 +65,6 @@ __all__ = [
     "NullRegistry",
     "default_registry",
     "set_default_registry",
-    "DispatchProfile",
-    "dispatch_profile",
-    "measured_depth",
     "JsonlSink",
     "NullRecorder",
     "Span",

@@ -1,5 +1,6 @@
 """Span/trace layer: nested spans + standalone events with a JSONL
-event-log exporter (DESIGN.md §12).
+event-log exporter, mirrored into the JAX profiler's trace (DESIGN.md
+§12).
 
 A ``SpanRecorder`` holds a stack of open spans; ``span(...)`` is a
 context manager that opens a child of whatever span is currently open,
@@ -19,6 +20,14 @@ by convention)::
     {"type": "span", "name": ..., "id": ..., "parent": ..., "t0": ...,
      "t1": ..., "dur": ..., "attrs": {...}, "events": [...]}
     {"type": "event", "name": ..., "t": ..., "span": ..., "attrs": {...}}
+
+An enabled recorder also opens a ``jax.profiler.TraceAnnotation`` under
+each span's name for the span's lifetime, so while the profiler runs
+the spans land in its ``.xplane.pb`` on the host thread's line, on the
+same clock as the device ops and nested like the spans themselves.
+Outside a profiled interval an annotation costs about a microsecond.
+Where JAX is not installed there is nothing to mirror into and the
+recorder skips it.
 
 ``NullRecorder`` is the zero-cost twin: ``span()`` returns a shared
 no-op context manager, so instrumented code pays one method call when
@@ -47,7 +56,7 @@ class Span:
     attributes, ``event`` appends a timestamped point-in-time record."""
 
     __slots__ = ("name", "id", "parent", "t0", "t1", "attrs", "events",
-                 "_rec")
+                 "_rec", "_ann")
 
     def __init__(self, name: str, sid: int, parent: Optional[int],
                  t0: float, rec: "SpanRecorder"):
@@ -59,6 +68,7 @@ class Span:
         self.attrs: Dict[str, object] = {}
         self.events: List[dict] = []
         self._rec = rec
+        self._ann = None  # the open profiler annotation, if mirrored
 
     @property
     def duration(self) -> Optional[float]:
@@ -126,6 +136,22 @@ class JsonlSink:
             self._f.close()
 
 
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent
+    (``repro.obs`` itself depends on nothing)."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _close_annotation(span: Span) -> None:
+    ann, span._ann = span._ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 class SpanRecorder:
     """Explicit span lifecycle + the nesting stack (module docstring).
 
@@ -144,6 +170,7 @@ class SpanRecorder:
     def __init__(self, clock=time.perf_counter, sink=None,
                  max_spans: int = 65536):
         self.clock = clock
+        self._annotate = _trace_annotation()
         self.sink = sink
         self.spans: "collections.deque[Span]" = collections.deque(
             maxlen=max_spans
@@ -158,6 +185,9 @@ class SpanRecorder:
         s = Span(name, next(self._ids), parent, self.clock(), self)
         if attrs:
             s.attrs.update(attrs)
+        if self._annotate is not None:
+            s._ann = self._annotate(name)
+            s._ann.__enter__()
         self._stack.append(s)
         return s
 
@@ -165,11 +195,15 @@ class SpanRecorder:
         if attrs:
             span.attrs.update(attrs)
         span.t1 = self.clock()
-        # tolerate out-of-order ends defensively: pop through the span
+        # tolerate out-of-order ends defensively: pop through the span,
+        # closing the annotations of inner spans left open, innermost
+        # first
         while self._stack:
             top = self._stack.pop()
             if top is span:
                 break
+            _close_annotation(top)
+        _close_annotation(span)
         self.spans.append(span)
         if self.sink is not None:
             self.sink.write(span.to_dict())

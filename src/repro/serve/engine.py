@@ -379,7 +379,7 @@ class DecodeEngine:
         # §12 accounting: every counter lives in the registry (stats()
         # reads it back), spans go through the recorder (no-op default)
         self.registry = registry if registry is not None else MetricsRegistry()
-        self.recorder = recorder if recorder is not None else NullRecorder()
+        self.recorder = recorder
         r = self.registry
         self._m_requests = r.counter(
             "engine_requests_total",
@@ -466,6 +466,19 @@ class DecodeEngine:
             "reason (nan/clamped)",
         )
 
+    @property
+    def recorder(self) -> SpanRecorder:
+        return self._recorder
+
+    @recorder.setter
+    def recorder(self, recorder: Optional[SpanRecorder]) -> None:
+        """The span recorder (None = the no-op ``NullRecorder``); a new
+        one reaches the decoders already built too, whose
+        ``decoder.*`` spans nest under the engine's."""
+        self._recorder = recorder if recorder is not None else NullRecorder()
+        for dec in (*self._decoders.values(), *self._xla_decoders.values()):
+            dec.recorder = self._recorder
+
     # -- decoders / jit-fn cache ------------------------------------------
 
     def _decoder(self, code: str) -> ViterbiDecoder:
@@ -481,6 +494,8 @@ class DecodeEngine:
                 code,
                 precision=self.precision,
                 use_kernel=self.use_kernel,
+                recorder=self.recorder,
+                registry=self.registry,
                 **kw,
             )
         return self._decoders[code]
@@ -500,6 +515,8 @@ class DecodeEngine:
                 code,
                 precision=self.precision,
                 use_kernel=False,
+                recorder=self.recorder,
+                registry=self.registry,
                 **kw,
             )
         return self._xla_decoders[code]
@@ -656,6 +673,10 @@ class DecodeEngine:
         """Enqueue one request; returns its Ticket (``dropped=True``
         under backpressure).  ``now`` is the submission timestamp —
         pass a virtual clock for deterministic tests/benches."""
+        with self.recorder.span("engine.submit"):
+            return self._submit(req, now)
+
+    def _submit(self, req: DecodeRequest, now: Optional[float]) -> Ticket:
         from repro.codes.registry import get_code
 
         now = time.monotonic() if now is None else now
@@ -736,33 +757,36 @@ class DecodeEngine:
         tickets completed by this call, in completion order (plus any
         completed out of band by close_session/eviction since the last
         poll)."""
-        now = time.monotonic() if now is None else now
-        self._check_hosts(now)
-        done, self._done_buffer = self._done_buffer, []
-        for key in sorted(self._queues):
-            q = self._queues[key]
-            while q and (
-                len(q) >= self.max_batch
-                or now - q[0][0].submitted >= self.max_wait[key[1]]
-            ):
-                done.extend(self._run_batch(key, q, now))
-        done.extend(self._run_sessions(now))
-        self._maybe_checkpoint(now)
-        return done
+        return self._run_due(now, drain=False)
 
     def drain(self, now: Optional[float] = None) -> List[Ticket]:
         """Graceful drain: decode everything still queued — partial
         cells included — and all pending session chunks.  Sessions stay
         open (close them via ``close_session``)."""
+        return self._run_due(now, drain=True)
+
+    def _run_due(self, now: Optional[float], drain: bool) -> List[Ticket]:
+        """``poll`` (due cells only) or ``drain`` (every cell), in one
+        ``engine.poll`` span."""
         now = time.monotonic() if now is None else now
-        self._check_hosts(now)
-        done, self._done_buffer = self._done_buffer, []
-        for key in sorted(self._queues):
-            q = self._queues[key]
-            while q:
-                done.extend(self._run_batch(key, q, now))
-        done.extend(self._run_sessions(now))
-        self._maybe_checkpoint(now)
+        rec = self.recorder
+        with rec.span("engine.poll") as sp:
+            n0 = self._m_batches.total() if rec.enabled else 0
+            self._check_hosts(now)
+            done, self._done_buffer = self._done_buffer, []
+            for key in sorted(self._queues):
+                q = self._queues[key]
+                while q and (
+                    drain
+                    or len(q) >= self.max_batch
+                    or now - q[0][0].submitted >= self.max_wait[key[1]]
+                ):
+                    done.extend(self._run_batch(key, q, now))
+            done.extend(self._run_sessions(now))
+            self._maybe_checkpoint(now)
+            if rec.enabled:
+                sp.set(n_batches=int(self._m_batches.total() - n0),
+                       n_done=len(done))
         return done
 
     def _run_batch(self, key, q, now: float) -> List[Ticket]:
@@ -816,12 +840,8 @@ class DecodeEngine:
                 "engine.dispatch", code=code_name, path=path,
                 f=f_cell, t=l_cell,
             ) as dsp:
-                prof = None
                 if rec.enabled:
-                    from repro.obs.profile import dispatch_profile
-
-                    prof = dispatch_profile(dec, path, f_cell, n_stages)
-                    dsp.set(**prof.span_attrs())
+                    dsp.set(h2d_arrays=1, h2d_bytes=dense.nbytes)
                 try:
                     path, out, retries = self._dispatch_with_faults(
                         code_name, fn, path, f_cell, l_cell,
@@ -841,11 +861,10 @@ class DecodeEngine:
                     bits, sdc_device = self.chaos.corrupt(bits)
                 else:
                     sdc_device = None
-                if prof is not None:
-                    wall = rec.clock() - dsp.t0
-                    dsp.set(**prof.achieved(wall))
+                if rec.enabled:
                     self._m_dispatch.observe(
-                        wall, code=code_name, path=path, f=f_cell, t=l_cell
+                        rec.clock() - dsp.t0,
+                        code=code_name, path=path, f=f_cell, t=l_cell,
                     )
             corrupt_ids: set = set()
             # §15 soft output is real-valued — no bit-identical shadow
@@ -1208,24 +1227,25 @@ class DecodeEngine:
         """Queue one LLR chunk on a session; the ticket completes (with
         the bits that became final) at the next poll/drain."""
         now = time.monotonic() if now is None else now
-        sess = self._sessions[sid]
-        shaped = self._shape_chunk(self._decoder(sess.code), llrs)
-        ticket = Ticket(
-            id=next(self._ids),
-            code=sess.code,
-            slo="throughput",
-            submitted=now,
-            n_out=-1,  # emission depends on stream position
-        )
-        if self.queue_depth() >= self.max_pending:
-            ticket.dropped = True
-            self._m_requests.inc(1, event="rejected", slo="throughput")
+        with self.recorder.span("engine.submit"):
+            sess = self._sessions[sid]
+            shaped = self._shape_chunk(self._decoder(sess.code), llrs)
+            ticket = Ticket(
+                id=next(self._ids),
+                code=sess.code,
+                slo="throughput",
+                submitted=now,
+                n_out=-1,  # emission depends on stream position
+            )
+            if self.queue_depth() >= self.max_pending:
+                ticket.dropped = True
+                self._m_requests.inc(1, event="rejected", slo="throughput")
+                return ticket
+            sess.pending.append((ticket, shaped))
+            self._sessions.move_to_end(sid)
+            sess.last_used = now
+            self._m_requests.inc(1, event="submitted", slo="throughput")
             return ticket
-        sess.pending.append((ticket, shaped))
-        self._sessions.move_to_end(sid)
-        sess.last_used = now
-        self._m_requests.inc(1, event="submitted", slo="throughput")
-        return ticket
 
     def _run_sessions(self, now: float) -> List[Ticket]:
         """Drain pending session chunks, one chunk per session per
@@ -1307,12 +1327,6 @@ class DecodeEngine:
                 "engine.dispatch", code=code_name, path="session",
                 f=f_cell, t=c,
             ) as dsp:
-                prof = None
-                if rec.enabled:
-                    from repro.obs.profile import dispatch_profile
-
-                    prof = dispatch_profile(dec, "session", f_cell, c)
-                    dsp.set(**prof.span_attrs())
                 attempt = retries = 0
                 while True:
                     try:
@@ -1359,11 +1373,10 @@ class DecodeEngine:
                     # leaks onto a later unrelated dispatch; sessions
                     # are outside the scrubber's coverage (DESIGN §14)
                     outs[0], _ = self.chaos.corrupt(outs[0])
-                if prof is not None:
-                    wall = rec.clock() - dsp.t0
-                    dsp.set(**prof.achieved(wall))
+                if rec.enabled:
                     self._m_dispatch.observe(
-                        wall, code=code_name, path="session", f=f_cell, t=c
+                        rec.clock() - dsp.t0,
+                        code=code_name, path="session", f=f_cell, t=c,
                     )
             done: List[Ticket] = []
             with rec.span("engine.emit", n=k):

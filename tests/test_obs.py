@@ -1,6 +1,7 @@
 """Observability subsystem (DESIGN.md §12): registry semantics,
 Prometheus round-trip through the validating smoke parser, span
-nesting + JSONL replay, the device-profile adapter, and the two engine
+nesting + JSONL replay, spans mirrored into the profiler's trace, the
+engine's and decoder's spans at each layer boundary, and the engine
 contracts — decode bits identical with tracing off/on for EVERY
 registry code, and ``stats()`` (registry-backed since §12) exactly
 matching an independent legacy recomputation of the same replayed
@@ -170,52 +171,146 @@ def test_null_recorder_is_inert():
     assert rec.find("x") == [] and rec.open_spans == 0
 
 
-# -- device-profile adapter ---------------------------------------------------
+# -- spans in the profiler's trace --------------------------------------------
 
-def test_dispatch_profile_attrs_and_achieved():
-    from repro.core.decoder import ViterbiDecoder
-    from repro.obs.profile import dispatch_profile
+def _host_events(trace_dir, prefix):
+    """(line name, [(name, start_ns, end_ns)]) of every host line of the
+    ``.xplane.pb`` under ``trace_dir`` holding events named ``prefix*``."""
+    from jax.profiler import ProfileData
 
-    from repro.roofline import PEAKS_BY_DEVICE_KIND, TPU_V5E
-
-    dec = ViterbiDecoder.from_standard("ccsds-k7")
-    prof = dispatch_profile(
-        dec, "batch", f_cell=32, n_stages=256,
-        hw=PEAKS_BY_DEVICE_KIND["TPU v5 lite"],
-    )
-    attrs = prof.span_attrs()
-    for key in ("hbm_bytes_modeled", "flops_modeled", "depth_modeled",
-                "intensity", "t_memory_us", "t_compute_us", "bottleneck"):
-        assert key in attrs, key
-    assert attrs["hbm_bytes_modeled"] > 0 and attrs["depth_modeled"] > 0
-    assert attrs["hw"] == TPU_V5E.name
-    # 1 s wall for a tiny cell: far off the v5e roofline but nonzero
-    ach = prof.achieved(wall_s=1.0)
-    assert 0.0 < ach["achieved_hbm_frac"] < 1.0
-    assert 0.0 < ach["achieved_flops_frac"] < 1.0
-    # lru cache: same cell -> same object, no traffic recomputation
-    assert dispatch_profile(dec, "batch", 32, 256, hw=TPU_V5E) is prof
-    # a device kind without peaks (this CPU host) gets modeled work and
-    # achieved rates, but no roofline terms and no fractions of peak
-    host = dispatch_profile(dec, "batch", 32, 256)
-    assert host.hw is None and host.hbm_bytes == prof.hbm_bytes
-    assert "t_compute_us" not in host.span_attrs()
-    ach = host.achieved(wall_s=1.0)
-    assert ach["achieved_hbm_Bps"] > 0
-    assert not any(k.endswith("_frac") for k in ach)
+    (path,) = trace_dir.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host"):
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name.startswith(prefix)]
+            if evs:
+                out.append((line.name, evs))
+    return out
 
 
-def test_measured_depth_counts_scan_trips():
-    from repro.obs.profile import measured_depth
+@pytest.mark.parametrize("kind", ["mirrored", "null"])
+def test_spans_mirror_into_profiler_trace(tmp_path, kind):
+    """An enabled SpanRecorder's nested spans come back from the
+    profiler's ``.xplane.pb`` on one host line, with the same names and
+    nesting; a NullRecorder writes nothing there."""
+    rec = {"mirrored": SpanRecorder(), "null": NullRecorder()}[kind]
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.span("obs_t.poll"):
+            with rec.span("obs_t.batch"):
+                with rec.span("obs_t.stack"):
+                    jnp.ones(8).block_until_ready()
+                with rec.span("obs_t.split"):
+                    pass
+    lines = _host_events(tmp_path, "obs_t.")
+    if kind != "mirrored":
+        assert lines == []
+        return
+    assert len(lines) == 1  # one host thread's line
+    evs = {name: (t0, t1) for name, t0, t1 in lines[0][1]}
+    assert sorted(evs) == sorted(s.name for s in rec.spans)
+    by_id = {s.id: s for s in rec.spans}
+    for s in rec.spans:
+        if s.parent is None:
+            continue
+        (c0, c1), (p0, p1) = evs[s.name], evs[by_id[s.parent].name]
+        assert p0 <= c0 and c1 <= p1, (s.name, by_id[s.parent].name)
+    # siblings do not overlap
+    assert evs["obs_t.stack"][1] <= evs["obs_t.split"][0]
 
-    def body(c, x):
-        return c + x, c
 
-    def fn(xs):
-        return jax.lax.scan(body, jnp.float32(0), xs)[0]
+def test_out_of_order_end_closes_inner_annotations(tmp_path):
+    """Ending an outer span while an inner one is open closes the inner
+    span's profiler annotation too, inside the outer one's."""
+    rec = SpanRecorder()
+    with jax.profiler.trace(str(tmp_path)):
+        outer = rec.start("obs_o.outer")
+        inner = rec.start("obs_o.inner")
+        rec.end(outer)
+    assert inner._ann is None and rec.open_spans == 0
+    (line,) = _host_events(tmp_path, "obs_o.")
+    evs = {name: (t0, t1) for name, t0, t1 in line[1]}
+    assert set(evs) == {"obs_o.outer", "obs_o.inner"}
+    assert evs["obs_o.outer"][0] <= evs["obs_o.inner"][0]
+    assert evs["obs_o.inner"][1] <= evs["obs_o.outer"][1]
 
-    aval = jax.ShapeDtypeStruct((37,), jnp.float32)
-    assert measured_depth(fn, aval) == 37
+
+def _session_engine(n_sessions, c=64, depth=64, recorder=None):
+    """Engine with ``n_sessions`` open ccsds-k7 sessions, each with one
+    ``c``-stage chunk queued, and the chunks' LLRs."""
+    rng = np.random.default_rng(13)
+    engine = DecodeEngine(decision_depth=depth, recorder=recorder)
+    chunks = {}
+    for i in range(n_sessions):
+        sid = engine.open_session("ccsds-k7", now=0.0)
+        chunks[sid] = rng.normal(0, 1, (3, c, 2)).astype(np.float32)
+    return engine, chunks
+
+
+def test_session_dispatch_decoder_spans():
+    """A session-group dispatch of 4 sessions nests engine.poll >
+    engine.batch > engine.dispatch > decoder.{stack,validate,launch,
+    split}, with one host-to-device copy per session chunk; a recorder
+    set after the decoders were built reaches them."""
+    engine, chunks = _session_engine(4)
+    for sid, llr in chunks.items():
+        engine.submit_chunk(sid, llr[0], now=0.0)
+    rec = SpanRecorder()
+    engine.recorder = rec
+    done = engine.poll(now=0.0)
+    assert len(done) == 4 and rec.open_spans == 0
+    (poll,) = rec.find("engine.poll")
+    assert poll.attrs == {"n_batches": 1, "n_done": 4}
+    (batch,) = rec.children(poll)
+    assert batch.name == "engine.batch"
+    (disp,) = [c for c in rec.children(batch) if c.name == "engine.dispatch"]
+    kids = {c.name: c for c in rec.children(disp)}
+    assert {"decoder.stack", "decoder.validate", "decoder.launch",
+            "decoder.split", "engine.device_wait"} <= set(kids)
+    assert kids["decoder.stack"].attrs["h2d_arrays"] == 4
+    assert kids["decoder.stack"].attrs["h2d_bytes"] == 4 * 64 * 2 * 4
+    assert len(rec.find("engine.submit")) == 0  # submitted untraced
+
+
+def test_batch_route_decoder_spans():
+    """A decode_batch route through the engine: engine.submit per
+    request, and decoder.depuncture / validate / launch under the
+    dispatch; the dense cell is the batch's one host-to-device copy."""
+    rec = SpanRecorder()
+    engine = DecodeEngine(max_batch=4, recorder=rec)
+    reqs = [_request("wifi-11a-r34", 60 + 6 * i, "throughput", seed=i)[1]
+            for i in range(3)]
+    tickets = [engine.submit(r, now=0.0) for r in reqs]
+    engine.drain(now=1.0)
+    assert all(t.bits is not None for t in tickets)
+    assert len(rec.find("engine.submit")) == 3
+    (disp,) = rec.find("engine.dispatch")
+    kids = {c.name: c for c in rec.children(disp)}
+    assert {"decoder.depuncture", "decoder.validate",
+            "decoder.launch"} <= set(kids)
+    assert kids["decoder.depuncture"].attrs["h2d_arrays"] == 0
+    assert disp.attrs["h2d_arrays"] == 1 and disp.attrs["h2d_bytes"] > 0
+    (poll,) = rec.find("engine.poll")
+    assert poll.attrs == {"n_batches": 1, "n_done": 3}
+
+
+def test_decoder_dispatch_total_in_engine_registry():
+    """The engine's decoders count decoder_dispatch_total{path} into
+    the engine's registry; the process default registry sees none."""
+    default = MetricsRegistry()
+    prev = set_default_registry(default)
+    try:
+        engine = DecodeEngine(max_batch=4)
+        engine.decode([_request("ccsds-k7", 64, "throughput", seed=1)[1]])
+    finally:
+        set_default_registry(prev)
+    paths = engine.registry.counter("decoder_dispatch_total").series()
+    assert sum(v for _, v in paths) == 1
+    assert paths[0][0]["path"] == engine.batch_log[-1]["path"]
+    assert "decoder_dispatch_total" not in default.snapshot()
 
 
 # -- engine contracts ---------------------------------------------------------
@@ -248,7 +343,31 @@ def test_engine_bits_identical_obs_on_off(tmp_path):
     # and the trace actually covered the work
     assert len(rec.find("engine.batch")) == len(engine_on.batch_log)
     disp = rec.find("engine.dispatch")
-    assert disp and all("hbm_bytes_modeled" in s.attrs for s in disp)
+    assert disp and all("h2d_arrays" in s.attrs for s in disp)
+
+
+def test_session_bits_identical_obs_on_off():
+    """The session twin: fused session dispatches emit the same bits
+    with tracing disabled and with a live, mirrored SpanRecorder."""
+    out = {}
+    for on in (False, True):
+        rec = SpanRecorder() if on else None
+        engine, chunks = _session_engine(3, recorder=rec)
+        bits = {sid: [] for sid in chunks}
+        for r in range(3):
+            tks = {sid: engine.submit_chunk(sid, llr[r], now=float(r))
+                   for sid, llr in chunks.items()}
+            engine.poll(now=float(r))
+            for sid, t in tks.items():
+                bits[sid].append(t.bits)
+        for sid in chunks:
+            bits[sid].append(engine.close_session(sid))
+        out[on] = {sid: np.concatenate(b) for sid, b in bits.items()}
+        if on:
+            assert len(rec.find("decoder.split")) == 3
+    assert out[False].keys() == out[True].keys()
+    for sid in out[False]:
+        np.testing.assert_array_equal(out[False][sid], out[True][sid])
 
 
 def test_stats_match_legacy_recomputation():
