@@ -191,6 +191,17 @@ def _window_valid(pos: int, t_steps: int, depth_steps: int) -> int:
     return max(0, pos + t_steps - depth_steps) - max(0, pos - depth_steps)
 
 
+@jax.jit
+def _split_frames(lam: jnp.ndarray, hist: jnp.ndarray, bits: jnp.ndarray):
+    """One-frame pieces of a fused group's carries and window bits, in
+    ONE program (DESIGN.md §10): (lam (F, S), hist (D, F, W), bits
+    (F, n)) -> F pieces of each.  Keyed on the shapes alone, so on the
+    group's total frame count (the engine's rung), never on how many
+    sessions share it."""
+    F = lam.shape[0]
+    return jnp.split(lam, F), jnp.split(hist, F, axis=1), jnp.split(bits, F)
+
+
 @functools.partial(jax.jit, static_argnames=("tables", "final_state"))
 def _flush_step(
     hist: jnp.ndarray,
@@ -882,9 +893,10 @@ class ViterbiDecoder:
         ``states`` are StreamStates of this decoder (same decision
         depth); ``chunks`` the matching (f_i, c, beta) LLR chunks, all
         with the same step count c.  The states are stacked along the
-        frame axis, run through ONE ``_dispatch_chunk`` (one jit entry
-        per (depth, total F, c) shape — the engine pads total F to a
-        cell rung), and split back.  Sessions may sit at *different*
+        frame axis (the chunks in one host-to-device copy), run through ONE
+        ``_dispatch_chunk`` (one jit entry per (depth, total F, c) shape
+        — the engine pads total F to a cell rung), and split back by one
+        program keyed on the same shape.  Sessions may sit at *different*
         stream positions: the delayed-decision window is sliced per
         state with the same emission rule as ``decode_chunk``, so each
         session's emitted bits are identical to driving it alone.
@@ -902,10 +914,9 @@ class ViterbiDecoder:
             raise ValueError(f"mixed decision depths {sorted(depths)}")
         rec = self.recorder
         with rec.span("decoder.stack") as sp:
-            if rec.enabled:
-                n_h2d, h2d_bytes = _host_arrays(chunks)
-                sp.set(h2d_arrays=n_h2d, h2d_bytes=h2d_bytes)
-            chunks = [jnp.asarray(ch) for ch in chunks]
+            # the chunks stack on the host and cross to the device as
+            # ONE copy
+            chunks = [np.asarray(ch) for ch in chunks]
             steps = {ch.shape[1] for ch in chunks}
             if len(steps) != 1:
                 raise ValueError(f"mixed chunk lengths {sorted(steps)}")
@@ -915,7 +926,10 @@ class ViterbiDecoder:
                         f"state has {s.n_frames} frames, chunk "
                         f"{ch.shape[0]}"
                     )
-            stacked = jnp.concatenate(chunks, axis=0)
+            stacked = np.concatenate(chunks, axis=0)
+            if rec.enabled:
+                sp.set(h2d_arrays=1, h2d_bytes=stacked.nbytes)
+            stacked = jnp.asarray(stacked)
             hist = jnp.concatenate([s.hist for s in states], axis=1)
             lam = jnp.concatenate([s.lam for s in states], axis=0)
         with rec.span("decoder.validate"):
@@ -929,22 +943,33 @@ class ViterbiDecoder:
                 self.renorm_guard.due(s.pos + T, T) for s in states):
             lam2, _ = self.renorm_guard.observe(lam2, t_chunk=T)
         new_states, outs, off = [], [], 0
-        with rec.span("decoder.split"):
+        with rec.span("decoder.split") as sp:
+            # one program splits the group into one-frame pieces; a
+            # state of several frames (the engine's pad, multi-frame
+            # callers) is sliced alone, and a state still in warm-up
+            # slices its emission window
+            ops = sliced = 0
+            if any(s.n_frames == 1 for s in states):
+                pieces = _split_frames(lam2, hist2, bits)
+                ops += 1
             for s in states:
                 f = s.n_frames
-                b = bits[off : off + f]
+                if f == 1:
+                    lm, h, b = (p[off] for p in pieces)
+                else:
+                    lm = lam2[off : off + f]
+                    h = hist2[:, off : off + f]
+                    b = bits[off : off + f]
+                    ops += 3
+                    sliced += 1
                 n_valid = _window_valid(s.pos, T, D)
-                outs.append(
-                    b[:, (T - n_valid) * self.rho:] if n_valid else b[:, :0]
-                )
-                new_states.append(
-                    StreamState(
-                        lam=lam2[off : off + f],
-                        hist=hist2[:, off : off + f],
-                        pos=s.pos + T,
-                    )
-                )
+                if n_valid < T:
+                    b = b[:, (T - n_valid) * self.rho:]
+                    ops += 1
+                outs.append(b)
+                new_states.append(StreamState(lam=lm, hist=h, pos=s.pos + T))
                 off += f
+            sp.set(split_ops=ops, sliced=sliced)
         return new_states, outs
 
     def flush_stream(

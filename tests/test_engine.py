@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 from repro.codes import REGISTRY, encode_standard, get_code, standard_llrs
+from repro.core import decoder as decoder_mod
 from repro.core.decoder import ViterbiDecoder
 from repro.core.kernel_geometry import pick_cell_frames, pick_cell_length
+from repro.obs import SpanRecorder
 from repro.serve.engine import DecodeEngine, DecodeRequest
 
 
@@ -276,31 +278,92 @@ def test_session_eviction_is_forced_flush():
     np.testing.assert_array_equal(got, ref)
 
 
-def test_decode_chunk_multi_matches_solo():
+@pytest.mark.parametrize(
+    "layout, on_device",
+    [
+        # (frames, chunks already consumed) per state; at depth 128 and
+        # 96-stage chunks a state emits nothing, part of the window and
+        # all of it after 0, 1 and 2 chunks
+        pytest.param([(1, 0), (1, 1), (1, 2), (1, 3)], False,
+                     id="one_frame_positions"),
+        pytest.param([(1, 1), (2, 0), (1, 2), (3, 1)], False,
+                     id="multi_frame"),
+        pytest.param([(1, 2), (1, 0), (1, 1), (5, 0)], False,
+                     id="trailing_pad"),
+        pytest.param([(1, 2), (1, 0), (2, 1)], True, id="device_chunks"),
+    ],
+)
+def test_decode_chunk_multi_matches_solo(layout, on_device):
     """Decoder-level contract under the engine: decode_chunk_multi on
-    states at different positions == each state driven alone."""
+    states at different positions and of different frame counts ==
+    each state driven alone, bits and carries alike.  The trailing
+    many-frame state is the engine's pad.  The engine hands host
+    chunks; chunks already on the device are accepted too."""
     rng = np.random.default_rng(11)
     dec = ViterbiDecoder.from_standard("ccsds-k7", decision_depth=128)
-    a = rng.normal(0, 1, (1, 192, 2)).astype(np.float32)
-    b = rng.normal(0, 1, (2, 192, 2)).astype(np.float32)
-    sa = dec.init_stream_state(1, initial_state=None)
-    sb = dec.init_stream_state(2, initial_state=None)
-    sa, _ = dec.decode_chunk(sa, a)  # advance A only
-    ref_a, _ = dec.decode_chunk(sa, a)
-    ref_b, _ = dec.decode_chunk(sb, b)
-    (got_a, got_b), outs = dec.decode_chunk_multi([sa, sb], [a, b])
-    solo_a = dec.decode_chunk(sa, a)[1]
-    solo_b = dec.decode_chunk(sb, b)[1]
-    np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(solo_a))
-    np.testing.assert_array_equal(np.asarray(outs[1]), np.asarray(solo_b))
-    np.testing.assert_array_equal(np.asarray(got_a.lam), np.asarray(ref_a.lam))
-    np.testing.assert_array_equal(np.asarray(got_b.hist),
-                                  np.asarray(ref_b.hist))
-    assert got_a.pos == ref_a.pos and got_b.pos == ref_b.pos
+    c = 96
+
+    def chunk(f):
+        x = rng.normal(0, 1, (f, c, 2)).astype(np.float32)
+        return jnp.asarray(x) if on_device else x
+
+    states, chunks = [], []
+    for f, consumed in layout:
+        s = dec.init_stream_state(f, initial_state=None)
+        for _ in range(consumed):
+            s, _ = dec.decode_chunk(s, chunk(f))
+        states.append(s)
+        chunks.append(chunk(f))
+    got, outs = dec.decode_chunk_multi(states, chunks)
+    assert len(got) == len(outs) == len(states)
+    for s, ch, g, out in zip(states, chunks, got, outs):
+        ref, solo = dec.decode_chunk(s, ch)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(solo))
+        np.testing.assert_array_equal(np.asarray(g.lam), np.asarray(ref.lam))
+        np.testing.assert_array_equal(np.asarray(g.hist),
+                                      np.asarray(ref.hist))
+        assert g.pos == ref.pos and g.lam.shape == ref.lam.shape
     with pytest.raises(ValueError):
-        dec.decode_chunk_multi([sa], [a, b])
+        dec.decode_chunk_multi(states[:1], chunks)
     with pytest.raises(ValueError):
-        dec.decode_chunk_multi([sa, sb], [a, b[:, :96]])
+        dec.decode_chunk_multi(states[:2], [chunks[0], chunks[1][:, :48]])
+
+
+def test_session_split_keyed_on_rung():
+    """Groups of 3, 5 and 8 one-frame states padded to rung 8 share ONE
+    compiled split program (keyed on the rung, not the session count);
+    with no state in warm-up and no pad, the split is one device
+    operation and the stack one host-to-device copy."""
+    rng = np.random.default_rng(14)
+    rec = SpanRecorder()
+    # depth 64 and 88-stage chunks: a shape no other test splits; one
+    # chunk takes a fresh state out of warm-up
+    dec = ViterbiDecoder.from_standard(
+        "ccsds-k7", decision_depth=64, recorder=rec
+    )
+    c, rung = 88, 8
+    split = decoder_mod._split_frames
+    states = [dec.init_stream_state(1) for _ in range(rung)]
+    for k in (3, 5, 8, 8):
+        group = states[:k]
+        if k < rung:
+            group = group + [dec.init_stream_state(rung - k)]
+        chunks = [rng.normal(0, 1, (s.n_frames, c, 2)).astype(np.float32)
+                  for s in group]
+        new, _ = dec.decode_chunk_multi(group, chunks)
+        states[:k] = new[:k]
+        sp = rec.find("decoder.split")[-1]
+        stack = rec.find("decoder.stack")[-1]
+        assert sp.attrs["sliced"] == (1 if k < rung else 0)
+        assert stack.attrs["h2d_arrays"] == 1
+        assert stack.attrs["h2d_bytes"] == rung * c * 2 * 4
+        if k == 3:
+            # the process-wide cache may already hold this shape; what
+            # matters is that no later session count adds to it
+            compiled = split._cache_size()
+    assert split._cache_size() == compiled
+    # the last group: all 8 states past warm-up, no pad
+    assert sp.attrs == {"split_ops": 1, "sliced": 0}
 
 
 def test_session_groups_respect_max_batch():

@@ -253,8 +253,8 @@ def _session_engine(n_sessions, c=64, depth=64, recorder=None):
 def test_session_dispatch_decoder_spans():
     """A session-group dispatch of 4 sessions nests engine.poll >
     engine.batch > engine.dispatch > decoder.{stack,validate,launch,
-    split}, with one host-to-device copy per session chunk; a recorder
-    set after the decoders were built reaches them."""
+    split}, with one host-to-device copy for the group's chunks; a
+    recorder set after the decoders were built reaches them."""
     engine, chunks = _session_engine(4)
     for sid, llr in chunks.items():
         engine.submit_chunk(sid, llr[0], now=0.0)
@@ -270,8 +270,12 @@ def test_session_dispatch_decoder_spans():
     kids = {c.name: c for c in rec.children(disp)}
     assert {"decoder.stack", "decoder.validate", "decoder.launch",
             "decoder.split", "engine.device_wait"} <= set(kids)
-    assert kids["decoder.stack"].attrs["h2d_arrays"] == 4
+    # the four host chunks stack on the host and cross in ONE copy
+    assert kids["decoder.stack"].attrs["h2d_arrays"] == 1
     assert kids["decoder.stack"].attrs["h2d_bytes"] == 4 * 64 * 2 * 4
+    # one split program for the group, plus one emission-window slice
+    # per session still in warm-up (all four are fresh); no pad state
+    assert kids["decoder.split"].attrs == {"split_ops": 1 + 4, "sliced": 0}
     assert len(rec.find("engine.submit")) == 0  # submitted untraced
 
 
