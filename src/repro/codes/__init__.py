@@ -2,7 +2,12 @@
 convolutional codes (CCSDS/DVB-S/802.11a/LTE TBCC/GSM), puncturing /
 rate-matching, and tail-biting (WAVA) decode — all behind the
 ``ViterbiDecoder`` front door via ``ViterbiDecoder.from_standard``."""
-from .puncture import PuncturePattern, depuncture, puncture  # noqa: F401
+from .puncture import (  # noqa: F401
+    PuncturePattern,
+    depuncture,
+    depuncture_np,
+    puncture,
+)
 from .registry import (  # noqa: F401
     REGISTRY,
     StandardCode,

@@ -11,7 +11,10 @@ kernel changes — the erasure argument is spelled out in DESIGN.md §7.
 Both ``puncture`` and ``depuncture`` compile to static gathers/scatters
 (the index vector is a numpy constant derived from the pattern and the
 static stage count), so they are jit- and vmap-friendly and fuse into
-the surrounding decode program.
+the surrounding decode program.  ``depuncture_np`` is the host twin of
+``depuncture``, with the same index map and contract, for inputs that
+are still numpy arrays and should cross to the device only once, already
+depunctured (the engine's session chunks, DESIGN.md §10).
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["PuncturePattern", "puncture", "depuncture"]
+__all__ = ["PuncturePattern", "puncture", "depuncture", "depuncture_np"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,15 +132,9 @@ def puncture(coded: jnp.ndarray, pattern: PuncturePattern) -> jnp.ndarray:
     return flat[..., idx]
 
 
-def depuncture(
-    kept: jnp.ndarray, pattern: PuncturePattern, n: int = None
-) -> jnp.ndarray:
-    """(..., Lp) kept LLRs -> (..., n, beta) with zero-LLR erasures.
-
-    ``n`` (stage count) defaults to the smallest stage count consistent
-    with Lp; pass it explicitly when trailing stages are fully punctured.
-    """
-    lp = kept.shape[-1]
+def _scatter_map(pattern: PuncturePattern, lp: int, n: int = None):
+    """(n, kept indices) for an Lp-long kept stream: ``n`` defaults to
+    the smallest stage count consistent with Lp."""
     if n is None:
         n = pattern.stages_for(lp)
     idx = pattern.kept_indices(n)
@@ -146,7 +143,33 @@ def depuncture(
             f"punctured length {lp} inconsistent with n={n} stages "
             f"(expected {idx.shape[0]})"
         )
+    return n, idx
+
+
+def depuncture(
+    kept: jnp.ndarray, pattern: PuncturePattern, n: int = None
+) -> jnp.ndarray:
+    """(..., Lp) kept LLRs -> (..., n, beta) with zero-LLR erasures.
+
+    ``n`` (stage count) defaults to the smallest stage count consistent
+    with Lp; pass it explicitly when trailing stages are fully punctured.
+    """
+    n, idx = _scatter_map(pattern, kept.shape[-1], n)
     beta = pattern.beta
     flat = jnp.zeros(kept.shape[:-1] + (n * beta,), kept.dtype)
     flat = flat.at[..., idx].set(kept)
+    return flat.reshape(kept.shape[:-1] + (n, beta))
+
+
+def depuncture_np(
+    kept: np.ndarray, pattern: PuncturePattern, n: int = None
+) -> np.ndarray:
+    """``depuncture`` on the host: (..., Lp) kept LLRs -> (..., n, beta)
+    numpy, zero at the erasures, the dtype kept.  The index map is the
+    pattern's cached ``kept_indices(n)``."""
+    kept = np.asarray(kept)
+    n, idx = _scatter_map(pattern, kept.shape[-1], n)
+    beta = pattern.beta
+    flat = np.zeros(kept.shape[:-1] + (n * beta,), kept.dtype)
+    flat[..., idx] = kept
     return flat.reshape(kept.shape[:-1] + (n, beta))

@@ -85,6 +85,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.codes.puncture import depuncture_np
 from repro.core.decoder import ViterbiDecoder
 from repro.core.kernel_geometry import (
     ENGINE_MIN_CELL,
@@ -396,6 +397,10 @@ class DecodeEngine:
         self._m_elems = r.counter(
             "engine_llr_elems_total",
             "LLR elements moved per batch, kind=real|pad",
+        )
+        self._m_erasures = r.counter(
+            "engine_erasures_total",
+            "zero LLRs re-inserted into punctured session chunks, by code",
         )
         self._m_sessions = r.counter(
             "engine_sessions_total",
@@ -1188,25 +1193,33 @@ class DecodeEngine:
         self._m_open_sessions.set(len(self._sessions))
         return sid
 
-    def _shape_chunk(self, dec: ViterbiDecoder, llrs: np.ndarray):
-        """One session chunk -> shaped (1, c, beta) stages.  Punctured
-        sessions submit serial kept-LLR chunks in whole pattern periods
-        (so per-chunk depuncturing equals whole-stream depuncturing);
-        stage counts must sit on the rho grid (ring steps are radix)."""
+    def _shape_chunk(self, code: str, llrs: np.ndarray):
+        """One session chunk -> shaped (1, c, beta) stages, a numpy
+        array.  Punctured sessions submit serial kept-LLR chunks in whole
+        pattern periods (so per-chunk depuncturing equals whole-stream
+        depuncturing), depunctured here on the host: the chunk reaches
+        the device once, in ``decode_chunk_multi``'s stacked copy.
+        Stage counts must sit on the rho grid (ring steps are radix)."""
+        dec = self._decoder(code)
         llrs = np.asarray(llrs, np.float32)
-        if dec.puncture is not None:
+        pat = dec.puncture
+        if pat is not None:
             if llrs.ndim != 1:
                 raise ValueError(
                     "punctured sessions take serial (Lp,) chunks, got "
                     f"shape {llrs.shape}"
                 )
-            kept = dec.puncture.n_kept
-            if llrs.shape[0] % kept:
+            lp = llrs.shape[0]
+            if lp % pat.n_kept:
                 raise ValueError(
                     f"serial session chunks must be whole puncture "
-                    f"periods ({kept} kept LLRs); got {llrs.shape[0]}"
+                    f"periods ({pat.n_kept} kept LLRs); got {lp}"
                 )
-            shaped = np.asarray(dec.depunctured(llrs[None]))
+            erased = lp // pat.n_kept * pat.period * pat.beta - lp
+            with self.recorder.span("engine.depuncture", kept=lp,
+                                    erased=erased):
+                shaped = depuncture_np(llrs[None], pat)
+            self._m_erasures.inc(erased, code=code)
         else:
             if llrs.ndim != 2 or llrs.shape[1] != dec.spec.beta:
                 raise ValueError(
@@ -1229,7 +1242,7 @@ class DecodeEngine:
         now = time.monotonic() if now is None else now
         with self.recorder.span("engine.submit"):
             sess = self._sessions[sid]
-            shaped = self._shape_chunk(self._decoder(sess.code), llrs)
+            shaped = self._shape_chunk(sess.code, llrs)
             ticket = Ticket(
                 id=next(self._ids),
                 code=sess.code,

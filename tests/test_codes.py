@@ -13,6 +13,7 @@ from repro.codes import (
     REGISTRY,
     PuncturePattern,
     depuncture,
+    depuncture_np,
     encode_standard,
     get_code,
     list_codes,
@@ -81,6 +82,27 @@ def test_puncture_batched_and_vmap():
     np.testing.assert_allclose(
         np.asarray(v), np.asarray(depuncture(kept, pat))
     )
+
+
+PUNCTURED = sorted(n for n, c in REGISTRY.items() if c.puncture is not None)
+
+
+@pytest.mark.parametrize("periods", [1, 2, 3])
+@pytest.mark.parametrize("name", PUNCTURED)
+def test_depuncture_np_equals_depuncture(name, periods):
+    """The host twin re-inserts the erasures where the device scatter
+    does, for one stream and a batch, and keeps the input's dtype."""
+    pat = get_code(name).puncture
+    rng = np.random.default_rng(zlib.crc32(name.encode()) + periods)
+    kept = rng.normal(size=(3, periods * pat.n_kept)).astype(np.float32)
+    want = np.asarray(depuncture(jnp.asarray(kept), pat))
+    got = depuncture_np(kept, pat)
+    assert isinstance(got, np.ndarray) and got.dtype == np.float32
+    assert got.shape == (3, periods * pat.period, pat.beta)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(depuncture_np(kept[1], pat), want[1])
+    with pytest.raises(ValueError, match="inconsistent"):
+        depuncture_np(kept, pat, n=periods * pat.period + 1)
 
 
 def test_stages_for_inverts_punctured_len():

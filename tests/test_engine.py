@@ -10,10 +10,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.codes import REGISTRY, encode_standard, get_code, standard_llrs
+from repro.codes import (
+    REGISTRY,
+    depuncture,
+    encode_standard,
+    get_code,
+    standard_llrs,
+)
 from repro.core import decoder as decoder_mod
 from repro.core.decoder import ViterbiDecoder
 from repro.core.kernel_geometry import pick_cell_frames, pick_cell_length
+from repro.core.trellis import build_transitions
+from repro.core.viterbi_ref import forward_ref, traceback_ref
 from repro.obs import SpanRecorder
 from repro.serve.engine import DecodeEngine, DecodeRequest
 
@@ -251,6 +259,139 @@ def test_session_punctured_serial_chunks():
     with pytest.raises(ValueError):  # partial period rejected
         sid2 = engine.open_session("wifi-11a-r34", now=0.0)
         engine.submit_chunk(sid2, serial[0, :126], now=0.0)
+
+
+def test_punctured_session_chunk_is_depunctured_on_the_host(monkeypatch):
+    """A dvb-s-r78 chunk is depunctured with numpy inside
+    ``submit_chunk``, under an ``engine.depuncture`` span: no JAX
+    depuncture runs and nothing crosses to or from the device until the
+    group's one stacked copy."""
+    import importlib
+
+    # the package re-exports ``puncture`` (the function) over its module
+    puncture_mod = importlib.import_module("repro.codes.puncture")
+
+    def no_device_depuncture(*a, **k):
+        raise AssertionError("JAX depuncture on the session path")
+
+    monkeypatch.setattr(puncture_mod, "depuncture", no_device_depuncture)
+    rng = np.random.default_rng(15)
+    rec = SpanRecorder()
+    engine = DecodeEngine(decision_depth=256, recorder=rec)
+    sid = engine.open_session("dvb-s-r78", now=0.0)
+    serial = rng.normal(0, 1, 2 * 256).astype(np.float32)  # 64 periods
+    tickets = []
+    for lo in (0, 256):
+        with jax.transfer_guard("disallow_explicit"):
+            tickets.append(
+                engine.submit_chunk(sid, serial[lo: lo + 256], now=0.0)
+            )
+        shaped = engine._sessions[sid].pending[-1][1]
+        assert isinstance(shaped, np.ndarray) and shaped.shape == (1, 224, 2)
+        engine.poll(now=0.0)
+    assert all(t.done and t.error is None for t in tickets)
+    dep = rec.find("engine.depuncture")
+    assert len(dep) == 2
+    submits = {s.id for s in rec.find("engine.submit")}
+    for sp in dep:
+        assert sp.parent in submits
+        assert sp.attrs == {"kept": 256, "erased": 224 * 2 - 256}
+    assert engine.registry.counter("engine_erasures_total").value(
+        code="dvb-s-r78") == 2 * 192
+
+
+def _start_free_metric(llrs, bits, spec):
+    """Path metric of ``bits`` over the (n, beta) stages ``llrs``, from
+    the start state that suits them best (the sessions start with every
+    state equal), in float64."""
+    tr = build_transitions(spec)
+    theta = 1.0 - 2.0 * np.asarray(tr.out_bits, np.float64)
+    s = np.arange(spec.n_states)
+    metric = np.zeros(spec.n_states)
+    for t, u in enumerate(np.asarray(bits, np.int64)):
+        metric += theta[s, u] @ np.asarray(llrs[t], np.float64)
+        s = tr.next_state[s, u]
+    return float(metric.max())
+
+
+@pytest.mark.parametrize("ebn0", [8.0, 1.0])
+def test_dvbs_r78_sessions_match_reference(ebn0):
+    """Three DVB-S rate-7/8 sessions at different stream positions fuse
+    into one group, chunk after chunk, and each chunk's bits are the
+    scalar reference's ML decisions given the stream up to the front
+    when they were emitted (``viterbi_ref``, float64).  At high Eb/N0
+    the bits are equal; at low Eb/N0 near-ties may flip under float32
+    rounding, so their path metric must equal the reference's best
+    over the same stages within 1e-4."""
+    code = get_code("dvb-s-r78")
+    pat = code.puncture
+    chunk = 32 * pat.period  # 224 stages, 112 radix steps
+    lp = chunk // pat.period * pat.n_kept
+    # decision depth 256 -> 448 stages (2 chunks) after the stretch
+    engine = DecodeEngine(decision_depth=256)
+    n_chunks = {"a": 6, "b": 5, "c": 4}
+    serial, stages = {}, {}
+    for i, (name, m) in enumerate(sorted(n_chunks.items())):
+        key = jax.random.PRNGKey(100 + i)
+        bits = jax.random.bernoulli(key, 0.5, (1, m * chunk))
+        x = standard_llrs(jax.random.fold_in(key, 1),
+                          encode_standard(bits.astype(jnp.int32), code),
+                          ebn0, code)
+        serial[name] = np.asarray(x)[0]
+        stages[name] = np.asarray(depuncture(x, pat))[0]
+    sids = {}
+    sent = {name: 0 for name in n_chunks}
+    emitted = {name: 0 for name in n_chunks}
+    answers = []  # (session, bits, e0, e1, front)
+
+    def round_(names):
+        tks = {}
+        for name in names:
+            i = sent[name]
+            tks[name] = engine.submit_chunk(
+                sids[name], serial[name][i * lp: (i + 1) * lp], now=0.0
+            )
+            sent[name] += 1
+        engine.poll(now=0.0)
+        for name, tk in tks.items():
+            e0 = emitted[name]
+            emitted[name] += tk.bits.shape[0]
+            answers.append((name, tk.bits, e0, emitted[name],
+                            sent[name] * chunk))
+        return engine.batch_log[-1]["n_real"]
+
+    # a starts alone, b one chunk later, c two: then all three fuse
+    for start in ("a", "b", "c"):
+        sids[start] = engine.open_session(code.name, now=0.0)
+        if start != "c":
+            round_([n for n in sids])
+    while any(sent[n] < m for n, m in n_chunks.items()):
+        live = [n for n, m in n_chunks.items() if sent[n] < m]
+        assert round_(live) == len(live)
+    assert {a[0] for a in answers if a[3] > a[2]} == set(n_chunks)
+    for name in n_chunks:
+        tail = engine.close_session(sids[name])
+        n = n_chunks[name] * chunk
+        answers.append((name, tail, emitted[name], n, n))
+        emitted[name] += tail.shape[0]
+        assert emitted[name] == n  # every stage emitted once
+    fwd = {name: forward_ref(stages[name].astype(np.float64), code.spec,
+                             initial_state=None) for name in n_chunks}
+    for name, bits, e0, e1, front in answers:
+        if e1 == e0:
+            continue
+        lam, phi = fwd[name]
+        ref = traceback_ref(lam[:front], phi[:front], code.spec)
+        got = np.asarray(bits, np.int64)
+        if ebn0 >= 6.0:
+            np.testing.assert_array_equal(got, ref[e0:e1])
+            continue
+        spliced = ref.copy()
+        spliced[e0:e1] = got
+        x = stages[name][:front]
+        gap = (_start_free_metric(x, ref, code.spec)
+               - _start_free_metric(x, spliced, code.spec))
+        assert abs(gap) <= 1e-4, (name, e0, e1, gap)
 
 
 def test_session_eviction_is_forced_flush():

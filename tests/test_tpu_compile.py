@@ -125,6 +125,27 @@ def test_one_pass_kernel_compiles_at_default_depth(one_chip, w, packed):
     assert "tpu_custom_call" in text
 
 
+def test_one_pass_kernel_compiles_at_dvbs_r78_depth(one_chip, w):
+    """DVB-S rate 7/8's stretched depth (5120 x 14/8 = 8960 stages, the
+    deepest ring a benchmark cell runs) under 57344-stage session chunks
+    of 4 frames: the guard admits the packed ring and Mosaic compiles
+    it."""
+    depth, chunk, f = 8960 // RHO, 57344 // RHO, 4
+    tt = one_pass_time_tile(depth, chunk, S, True)
+    assert tt == 32
+    text, _ = _compile(
+        lambda b, l, h, w: acs_decode_fused_pallas(
+            b, l, h, w, n_states=S, n_slots=R, k=SPEC.k, rho=RHO,
+            time_tile=tt, pack_survivors=True, interpret=False,
+        ),
+        _sds((chunk, f, B), jnp.float32, one_chip),
+        _sds((f, S), jnp.float32, one_chip),
+        _sds((depth, f, S // 16), jnp.int32, one_chip),
+        w,
+    )
+    assert "tpu_custom_call" in text
+
+
 def test_one_pass_guard_refuses_what_mosaic_refuses(one_chip, w):
     """A ring beyond VMEM: the guard refuses the shape (so the decoder
     takes the two-pass path), and Mosaic, compiling it anyway at the
