@@ -856,16 +856,21 @@ class ViterbiDecoder:
         lam, _ = guard.observe(lam, t_chunk=t_chunk)
         return lam
 
-    def _dispatch_chunk(self, hist, lam, blocks):
+    def _dispatch_chunk(self, hist, lam, blocks, span=None):
         """One chunk window of ACS + delayed traceback on raw carries:
         (hist, lam, blocks) -> (hist', lam', window bits (F, T*rho)) for
         the T OLDEST window steps.  Picks the one-pass kernel or the
         two-pass XLA step by the shared §8 eligibility rule — the single
         dispatch point under ``decode_chunk`` and the engine's fused
-        multi-session step (``decode_chunk_multi``, DESIGN.md §10)."""
+        multi-session step (``decode_chunk_multi``, DESIGN.md §10).
+        ``span``, the enclosing ``decoder.launch``, gets the one-pass
+        tile and the ring steps walked per ACS step."""
         tt = self._one_pass_tile(blocks.shape[0], hist.shape[0])
         self._count_dispatch("chunk_one_pass" if tt else "chunk_two_pass")
         if tt:
+            if span is not None and self.recorder.enabled:
+                span.set(time_tile=tt,
+                         walk_per_step=(hist.shape[0] + tt) // tt)
             return _chunk_step_fused(
                 hist,
                 lam,
@@ -934,9 +939,9 @@ class ViterbiDecoder:
             lam = jnp.concatenate([s.lam for s in states], axis=0)
         with rec.span("decoder.validate"):
             stacked = self._harden(stacked, where="stream")
-        with rec.span("decoder.launch"):
+        with rec.span("decoder.launch") as sp:
             blocks = blocks_from_llrs(stacked, self.rho)
-            hist2, lam2, bits = self._dispatch_chunk(hist, lam, blocks)
+            hist2, lam2, bits = self._dispatch_chunk(hist, lam, blocks, sp)
         T = steps.pop() // self.rho
         D = depths.pop()
         if self.renorm_guard is not None and any(

@@ -95,22 +95,19 @@ def ring_auto_packed(n_states: int, pack_survivors: bool) -> bool:
     return pack_survivors or n_states % 16 == 0
 
 
+def _divisors(n: int):
+    """Every divisor of ``n`` >= 1, ascending."""
+    small = [c for c in range(1, math.isqrt(n) + 1) if n % c == 0]
+    return small + [n // c for c in reversed(small) if c * c != n]
+
+
 def pick_time_tile(d_steps: int, t_steps: int, target=None) -> int:
     """Largest time tile <= ``target`` dividing both the decision depth
     and the step count — the one-pass kernel needs the ring and the time
     grid on a common tile (DESIGN.md §8).  Always >= 1."""
     target = target or DEFAULT_TIME_TILE
     g = math.gcd(int(d_steps), int(t_steps))
-    best = 1
-    c = 1
-    while c * c <= g:
-        if g % c == 0:
-            if c <= target:
-                best = max(best, c)
-            if g // c <= target:
-                best = max(best, g // c)
-        c += 1
-    return best
+    return max((c for c in _divisors(g) if c <= target), default=1)
 
 
 def vmem_bytes(shape, dtype) -> int:
@@ -384,25 +381,35 @@ def one_pass_time_tile(
     n_slots: int = 4,
     matmul_dtype=jnp.float32,
 ):
-    """Shared one-pass eligibility check for every streaming entry point
+    """Shared one-pass tile rule for every streaming entry point
     (decoder.decode_chunk and the tiled window path): the time tile to
     launch the fused kernel with, or None when the shape should take the
-    two-pass fallback — packing impossible, no usable common tile (a
-    time_tile~1 kernel walks the whole ring per step), or a kernel
-    footprint (ring included, counted as Mosaic lays it out) beyond the
-    VMEM budget."""
+    two-pass fallback.
+
+    After each tile of TT steps the kernel walks its whole (D+TT)-step
+    ring to commit the oldest TT, so the walk costs (D+TT)/TT steps per
+    ACS step and the largest tile is the fastest.  The tile is the
+    largest common divisor of the depth and the step count (at most
+    ``time_tile`` when given) whose whole kernel footprint, ring
+    included and counted as Mosaic lays it out, fits the VMEM budget.
+    None when packing is impossible or no tile of at least
+    ``MIN_ONE_PASS_TILE`` fits (a tile near 1 walks the whole ring per
+    step)."""
     if d_steps <= 0 or t_steps <= 0:
         return None
     if ring_packed and n_states % 16:
         return None
-    tt = pick_time_tile(d_steps, t_steps, time_tile)
-    if tt < min(MIN_ONE_PASS_TILE, d_steps, t_steps):
-        return None
+    least = min(MIN_ONE_PASS_TILE, d_steps, t_steps)
     bf = block_frames or DEFAULT_BLOCK_FRAMES
-    if (
-        fused_decode_vmem_bytes(d_steps, tt, bf, n_states, llr_block,
-                                n_slots, ring_packed, matmul_dtype)
-        > KERNEL_VMEM_BUDGET
-    ):
-        return None
-    return tt
+    for tt in reversed(_divisors(math.gcd(int(d_steps), int(t_steps)))):
+        if tt < least:
+            break
+        if time_tile and tt > time_tile:
+            continue
+        if (
+            fused_decode_vmem_bytes(d_steps, tt, bf, n_states, llr_block,
+                                    n_slots, ring_packed, matmul_dtype)
+            <= KERNEL_VMEM_BUDGET
+        ):
+            return tt
+    return None

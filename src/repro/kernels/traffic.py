@@ -43,7 +43,8 @@ import numpy as np
 
 from repro import hlocount
 from repro.core.trellis import CODE_K7_CCSDS, CodeSpec, build_acs_tables
-from repro.core.viterbi import AcsPrecision, pick_time_tile, traceback
+from repro.core.kernel_geometry import one_pass_time_tile
+from repro.core.viterbi import AcsPrecision, traceback
 from repro.kernels.viterbi_acs import ring_dtype, ring_words
 
 __all__ = [
@@ -217,7 +218,7 @@ def one_pass_stream_traffic(
     W = ring_words(S, pack_survivors)
     ring_dt = ring_dtype(pack_survivors)
     mm = np.dtype(precision.matmul_dtype).itemsize
-    tt = pick_time_tile(D, T, time_tile)
+    tt = one_pass_time_tile(D, T, S, pack_survivors, time_tile)
 
     kb = {
         "blocks_in": T * F * B * mm,

@@ -53,15 +53,25 @@ def _replay_chunk_steps(blocks, lam0, hist0, tables, precision, tt, pack):
     return hist, lam, np.concatenate(outs, axis=1)
 
 
-@pytest.mark.parametrize("pack", [False, True], ids=["i8-ring", "packed"])
-@pytest.mark.parametrize("renorm", [True, False], ids=["renorm", "raw"])
-def test_fused_kernel_replays_chunk_state_machine(pack, renorm):
+@pytest.mark.parametrize(
+    "pack,renorm,TT",
+    [
+        pytest.param(pack, renorm, tt,
+                     id=f"{r_id}-{p_id}" + ("-tile_eq_depth" if tt == 16
+                                            else ""))
+        for tt in (8, 16)
+        for renorm, r_id in ((True, "renorm"), (False, "raw"))
+        for pack, p_id in ((False, "i8-ring"), (True, "packed"))
+    ],
+)
+def test_fused_kernel_replays_chunk_state_machine(pack, renorm, TT):
     """bits, exit metrics AND exit ring all exactly equal the XLA
     chunked path at chunk == time_tile, packed and unpacked, with and
-    without per-step renormalization."""
+    without per-step renormalization, at a tile below the depth and at
+    a tile equal to it (the ring then holds two tiles)."""
     tables = build_acs_tables(SPEC, 2)
     rng = np.random.default_rng(2)
-    F, n, D, TT = 3, 192, 16, 8
+    F, n, D = 3, 192, 16
     llr = jnp.asarray(rng.normal(0, 1, (F, n, SPEC.beta)), jnp.float32)
     blocks = blocks_from_llrs(llr, 2)
     lam0 = init_metric(F, SPEC.n_states, None)
@@ -123,13 +133,38 @@ def test_one_pass_engages_and_ring_is_packed():
     state = dec.init_stream_state(2)
     assert state.hist.dtype == jnp.int32
     assert state.hist.shape[-1] == SPEC.n_states // 16
-    assert dec._one_pass_tile(128, state.depth_steps) == 32
+    # the tile is the largest common divisor of chunk and depth that fits
+    assert dec._one_pass_tile(128, state.depth_steps) == 128
     # the default depth's packed ring fits; a ring beyond the VMEM
     # budget falls back to two-pass
     big = ViterbiDecoder(SPEC, use_kernel=True, decision_depth=20480)
-    assert big._one_pass_tile(2048, 2560) == 32
+    assert big._one_pass_tile(2048, 2560) == 512
     big.ring_packed = False  # unpacked 20480-stage ring: > VMEM budget
     assert big._one_pass_tile(2048, 10240) is None
+
+
+@pytest.mark.parametrize(
+    "depth,chunk_len,tile",
+    [(256, 512, 128), (512, 384, 64)],
+    ids=["tile_eq_depth", "tile_below_depth"],
+)
+def test_decode_chunk_default_tile_vs_oracle(depth, chunk_len, tile):
+    """decode_chunk at the tile the rule picks (no time_tile given),
+    chunk by chunk and flushed, == full decode_frames on a noisy
+    stream, and the chunks ran one-pass at that tile."""
+    bits, llr = _noisy_llrs(2, 1536, 0.5, seed=depth)
+    full = np.asarray(decode_frames(llr, SPEC, 2, None, None))
+    dec = ViterbiDecoder(SPEC, use_kernel=True, decision_depth=depth)
+    state = dec.init_stream_state(2)
+    assert dec._one_pass_tile(chunk_len // 2, state.depth_steps) == tile
+    outs = []
+    for lo in range(0, llr.shape[1], chunk_len):
+        state, out = dec.decode_chunk(state, llr[:, lo:lo + chunk_len])
+        outs.append(np.asarray(out))
+    outs.append(np.asarray(dec.flush_stream(state)))
+    got = np.concatenate(outs, axis=1)
+    np.testing.assert_array_equal(got, full)
+    assert (got != bits).mean() < 1e-3
 
 
 def test_one_pass_packed_unpacked_ring_parity():

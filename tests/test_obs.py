@@ -279,6 +279,33 @@ def test_session_dispatch_decoder_spans():
     assert len(rec.find("engine.submit")) == 0  # submitted untraced
 
 
+@pytest.mark.parametrize("use_kernel", [True, False],
+                         ids=["one_pass", "two_pass"])
+def test_session_launch_span_carries_one_pass_tile(use_kernel):
+    """A one-pass session group tags its decoder.launch span with the
+    time tile the rule picked and the ring steps walked per ACS step
+    (depth 64 steps, chunks of 128: tile 64, walk 2); the two-pass
+    step, and a disabled recorder, set neither."""
+    rng = np.random.default_rng(14)
+    rec = SpanRecorder()
+    engine = DecodeEngine(decision_depth=128, use_kernel=use_kernel,
+                          recorder=rec)
+    for _ in range(2):
+        sid = engine.open_session("ccsds-k7", now=0.0)
+        engine.submit_chunk(
+            sid, rng.normal(0, 1, (256, 2)).astype(np.float32), now=0.0)
+    assert len(engine.poll(now=0.0)) == 2
+    (launch,) = rec.find("decoder.launch")
+    paths = dict((lbl["path"], v) for lbl, v in engine.registry.counter(
+        "decoder_dispatch_total").series())
+    if use_kernel:
+        assert launch.attrs == {"time_tile": 64, "walk_per_step": 2}
+        assert paths == {"chunk_one_pass": 1}
+    else:
+        assert launch.attrs == {}
+        assert paths == {"chunk_two_pass": 1}
+
+
 def test_batch_route_decoder_spans():
     """A decode_batch route through the engine: engine.submit per
     request, and decoder.depuncture / validate / launch under the

@@ -106,10 +106,12 @@ def test_two_pass_kernel_compiles_at_decode_64k(one_chip, w, packed):
 
 @pytest.mark.parametrize("packed", [False, True], ids=["i8", "packed"])
 def test_one_pass_kernel_compiles_at_default_depth(one_chip, w, packed):
-    """The default 5120-stage decision depth: the guard admits the ring
-    and Mosaic compiles it (frames on lanes, (D+TT, W, BF))."""
+    """The default 5120-stage decision depth: the rule picks the largest
+    common tile of depth and chunk (512 steps, 6 ring steps walked per
+    ACS step), the guard admits the ring, and Mosaic compiles it (frames
+    on lanes, (D+TT, W, BF))."""
     tt = one_pass_time_tile(DEPTH_STEPS, CHUNK_STEPS, S, packed)
-    assert tt == 32
+    assert tt == 512
     W = S // 16 if packed else S
     text, _ = _compile(
         lambda b, l, h, w: acs_decode_fused_pallas(
@@ -128,22 +130,25 @@ def test_one_pass_kernel_compiles_at_default_depth(one_chip, w, packed):
 def test_one_pass_kernel_compiles_at_dvbs_r78_depth(one_chip, w):
     """DVB-S rate 7/8's stretched depth (5120 x 14/8 = 8960 stages, the
     deepest ring a benchmark cell runs) under 57344-stage session chunks
-    of 4 frames: the guard admits the packed ring and Mosaic compiles
-    it."""
+    of 4 frames: the rule picks a 896-step tile, the guard admits the
+    ring, and Mosaic compiles it, packed and int8."""
     depth, chunk, f = 8960 // RHO, 57344 // RHO, 4
-    tt = one_pass_time_tile(depth, chunk, S, True)
-    assert tt == 32
-    text, _ = _compile(
-        lambda b, l, h, w: acs_decode_fused_pallas(
-            b, l, h, w, n_states=S, n_slots=R, k=SPEC.k, rho=RHO,
-            time_tile=tt, pack_survivors=True, interpret=False,
-        ),
-        _sds((chunk, f, B), jnp.float32, one_chip),
-        _sds((f, S), jnp.float32, one_chip),
-        _sds((depth, f, S // 16), jnp.int32, one_chip),
-        w,
-    )
-    assert "tpu_custom_call" in text
+    for packed in (True, False):
+        tt = one_pass_time_tile(depth, chunk, S, packed)
+        assert tt == 896
+        W = S // 16 if packed else S
+        text, _ = _compile(
+            lambda b, l, h, w: acs_decode_fused_pallas(
+                b, l, h, w, n_states=S, n_slots=R, k=SPEC.k, rho=RHO,
+                time_tile=tt, pack_survivors=packed, interpret=False,
+            ),
+            _sds((chunk, f, B), jnp.float32, one_chip),
+            _sds((f, S), jnp.float32, one_chip),
+            _sds((depth, f, W), jnp.int32 if packed else jnp.int8,
+                 one_chip),
+            w,
+        )
+        assert "tpu_custom_call" in text
 
 
 def test_one_pass_guard_refuses_what_mosaic_refuses(one_chip, w):
