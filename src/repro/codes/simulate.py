@@ -2,10 +2,9 @@
 DESIGN.md §7): bits -> encode (zero-tail or tail-biting) -> puncture ->
 BPSK + AWGN -> LLR -> depuncture-aware ViterbiDecoder decode -> BER.
 
-Used by benchmarks/bench_ber.py's code×rate grid, the CI smoke job and
-tests/test_codes.py.  Eb/N0 is calibrated against the EFFECTIVE rate
-(puncturing raises the rate, so fewer coded bits share the same
-information energy).
+Used by the CI smoke job and tests/test_codes.py.  Eb/N0 is calibrated
+against the EFFECTIVE rate (puncturing raises the rate, so fewer coded
+bits share the same information energy).
 """
 from __future__ import annotations
 

@@ -33,14 +33,10 @@ class ViterbiConfig:
     pack_survivors: bool = False  # C2: 16 x 2-bit survivors per int32
     renorm: bool = True  # C3: per-step path-metric renormalization
     split_dot: bool = False  # C5: bf16 branch metrics + f32 metric routing
-    # one-pass kernel geometry (DESIGN.md §8); None = library defaults,
-    # per-cell tuned values live in KERNEL_CONFIGS (benchmarks/autotune.py)
-    time_tile: Optional[int] = None
-    block_frames: Optional[int] = None
-    # time-parallel decode (DESIGN.md §9): None = auto-select by shape;
-    # transfer_tile is the tuned matrix-scan tile (autotune sweep)
+    # time-parallel decode (DESIGN.md §9): None = auto-select by shape.
+    # Kernel geometry has no field here: it follows the shape
+    # (core/kernel_geometry.py).
     time_parallel: Optional[bool] = None
-    transfer_tile: Optional[int] = None
 
     @property
     def tiled(self) -> TiledDecoderConfig:
@@ -66,7 +62,7 @@ class ViterbiConfig:
 CONFIG = ViterbiConfig()  # paper-faithful baseline (Table I single-prec)
 
 # §Perf C4b: the adopted optimized service config — bf16 channel, packed
-# survivors, f=128 frames; BER bit-identical to baseline (EXPERIMENTS.md)
+# survivors, f=128 frames; BER bit-identical to baseline
 CONFIG_OPTIMIZED = ViterbiConfig(
     name="viterbi-k7-opt",
     frame_len=128,
@@ -115,78 +111,6 @@ VITERBI_CELLS = {
         "decode_gsm_bursts", 456, 4096, code="gsm-cs1"
     ),
 }
-
-
-@dataclasses.dataclass(frozen=True)
-class KernelConfig:
-    """Kernel geometry for a serving cell (DESIGN.md §8/§9).
-
-    Produced by ``benchmarks/autotune.py`` (block_frames x time_tile x
-    pack x matmul_dtype sweep for the one-pass streaming kernel, plus a
-    transfer_tile x matmul_dtype sweep for the time-parallel matrix
-    scan); ``apply_kernel_config`` threads it into a ViterbiConfig so
-    ``ViterbiDecoder.from_config`` picks it up.
-    """
-
-    block_frames: int = 256
-    time_tile: int = 32
-    pack_survivors: bool = True
-    matmul_dtype: str = "f32"  # "f32" | "bf16"
-    # §9 time-parallel matrix-scan tile; None = shape-derived default
-    transfer_tile: Optional[int] = None
-
-    def overrides(self) -> dict:
-        return dict(
-            block_frames=self.block_frames,
-            time_tile=self.time_tile,
-            pack_survivors=self.pack_survivors,
-            channel_bf16=self.matmul_dtype == "bf16",
-            transfer_tile=self.transfer_tile,
-        )
-
-
-# --- autotune: begin (written by `python -m benchmarks.autotune --apply`;
-#     do not edit inside this block by hand) ---
-KERNEL_CONFIGS = {
-    # streaming cells: packed VMEM ring + §9 transfer tile, tuned by benchmarks.autotune
-    "decode_1m": KernelConfig(256, 32, True, "f32", transfer_tile=512),
-    "decode_64k": KernelConfig(256, 32, True, "bf16", transfer_tile=128),
-    "decode_64k_dvb_r78": KernelConfig(256, 16, True, "f32", transfer_tile=512),
-    "decode_64k_wifi_r34": KernelConfig(256, 32, True, "f32", transfer_tile=128),
-    "decode_gsm_bursts": KernelConfig(128, 64, True, "f32", transfer_tile=114),
-}
-# --- autotune: end ---
-
-
-def kernel_config_for(cell_name: str) -> KernelConfig:
-    """Tuned one-pass geometry for a cell (library default otherwise).
-    Tail-biting cells (WAVA needs full survivors) have no entry — they
-    stay on the exact two-pass path."""
-    return KERNEL_CONFIGS.get(cell_name, KernelConfig())
-
-
-def apply_kernel_config(
-    cfg: ViterbiConfig, cell_name: str
-) -> ViterbiConfig:
-    """ViterbiConfig with the cell's tuned kernel geometry applied."""
-    if cell_name not in KERNEL_CONFIGS:
-        return cfg
-    return dataclasses.replace(
-        cfg, **kernel_config_for(cell_name).overrides()
-    )
-
-
-def config_for_cell(cell_name: str, **overrides) -> ViterbiConfig:
-    """Cell name -> ready ViterbiConfig: the cell's registry standard
-    plus its autotuned kernel geometry (KERNEL_CONFIGS) — the chokepoint
-    dryrun and hillclimb resolve cells through, so tuned
-    time_tile/block_frames/pack actually reach the decoder
-    (``ViterbiDecoder.from_config`` reads them).  The serve CLI resolves
-    by CODE name (``config_for_standard``), not by cell; apply a cell's
-    geometry there with ``apply_kernel_config`` when serving one."""
-    cell = VITERBI_CELLS[cell_name]
-    cfg = apply_kernel_config(config_for_standard(cell.code), cell_name)
-    return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 
 def input_specs(cfg: ViterbiConfig, cell: ViterbiCell):

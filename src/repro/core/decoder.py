@@ -388,8 +388,9 @@ class ViterbiDecoder:
         """Build from a configs.viterbi_k7.ViterbiConfig (the single
         vcfg -> decoder mapping; serve/step.py delegates here).  A config
         naming a registry standard (``vcfg.code``) inherits its puncture
-        pattern and termination; kernel-geometry fields autotuned into
-        the config cells (``benchmarks/autotune.py``) carry over too."""
+        pattern and termination.  Kernel geometry is not configured: the
+        decoder derives it from each call's shape, as ``from_standard``
+        does."""
         puncture, termination = None, "zero"
         code_name = getattr(vcfg, "code", None)
         if code_name:
@@ -411,10 +412,7 @@ class ViterbiDecoder:
             decision_depth=decision_depth or DEFAULT_DECISION_DEPTH,
             puncture=puncture,
             termination=termination,
-            time_tile=getattr(vcfg, "time_tile", None),
-            block_frames=getattr(vcfg, "block_frames", None),
             time_parallel=getattr(vcfg, "time_parallel", None),
-            transfer_tile=getattr(vcfg, "transfer_tile", None),
         )
 
     # -- §14 input hardening ----------------------------------------------

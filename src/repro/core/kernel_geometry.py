@@ -250,8 +250,7 @@ def forward_time_tile(
 def default_transfer_tile(t_steps: int) -> int:
     """Shape-derived transfer-tile target ~ sqrt(T'): balances the tile
     depth (formation/recovery loops) against the scan size (n_tiles S x S
-    composes) — the right neighbourhood on every backend; the autotuner
-    refines it per cell."""
+    composes) — the right neighbourhood on every backend."""
     target = 1
     while target * target < t_steps:
         target *= 2

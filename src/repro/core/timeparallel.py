@@ -35,8 +35,7 @@ the paper's dense tensor-op formulation so the MXU does the lifting:
      vmapped per-tile traceback emits all bits in tile depth.
 
 Total sequential depth: 3*tile + O(log2 n_tiles) dependent steps vs T'
-for the scan — the latency axis the serving benches measure
-(``benchmarks/bench_latency.py``).  The price is S x more formation work
+for the scan — the latency axis.  The price is S x more formation work
 (perfectly parallel), which is why the auto-select
 (``kernel_geometry.time_parallel_plan``) only engages when frames-only
 batching underfills the device (small-F / large-T serving).

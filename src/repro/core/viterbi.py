@@ -18,8 +18,7 @@ Frames are batched on the leading axis so that on TPU they occupy the
 
 Precision: the paper's Fig. 13 study maps to `AcsPrecision` — matmul inputs
 may be bf16 (paper: fp16 A/B), the accumulated path-metric carry must be f32
-(paper: fp32 C) or BER degrades; both choices are reproduced in
-benchmarks/bench_ber.py.
+(paper: fp32 C) or BER degrades.
 """
 from __future__ import annotations
 

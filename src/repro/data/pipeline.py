@@ -5,7 +5,7 @@ Two sources, both deterministic and host-shardable:
     architectures end-to-end without external corpora);
   * ``ChannelStream`` — the paper's pipeline (Fig. 12): random bits ->
     convolutional encoder -> BPSK+AWGN -> LLR frames, for the Viterbi
-    decoder service and BER benchmarks.
+    decoder service and the BER farm.
 
 Determinism: batch ``i`` of host ``h`` is a pure function of
 (seed, h, i), so restarts resume exactly (fault tolerance) and any host

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for the repo's entry points.
 
 ``enable_compile_cache()`` is called at the top of the ``main()`` of
-``chip_smoke.py``, ``repro.launch.serve`` and ``benchmarks/run.py`` —
-never at import, so importing the library changes no JAX setting.
+``chip_smoke.py`` and ``repro.launch.serve`` — never at import, so
+importing the library changes no JAX setting.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it by itself and
 this helper sets no path.  Otherwise the cache lives at the fixed path

@@ -186,7 +186,7 @@ def run_cell(arch: str, cell_name: str, multi_pod: bool, save: bool = True):
     try:
         if arch == "viterbi-k7":
             cell = vit.VITERBI_CELLS[cell_name]
-            vcfg = vit.config_for_cell(cell_name)
+            vcfg = vit.config_for_standard(cell.code)
             mf = viterbi_model_flops(vcfg, cell)
             with mesh:
                 lowered = _lower_viterbi_cell(vcfg, cell, mesh)

@@ -69,10 +69,8 @@ RAGGED requests, mixed codes, mixed latency/throughput SLOs.  The
 
 ``launch/serve.py --service engine`` drives a synthetic multi-tenant
 mix through this engine (``--chaos``/``--checkpoint-dir`` exercise the
-§13 machinery); ``benchmarks/bench_engine.py`` sweeps offered load into
-``BENCH_engine.json`` (p50/p99 per SLO class, batch occupancy, padding
-waste — schema in docs/BENCHMARKS.md) and ``benchmarks/bench_chaos.py``
-replays a kill schedule into ``BENCH_chaos.json``.
+§13 machinery); the on-chip benchmark (``bench/``, cells in
+``BENCHMARK.json``) measures it.
 """
 from __future__ import annotations
 

@@ -16,8 +16,7 @@ axis:
     the reference curve.
 
 ``python -m repro.verify.farm`` runs the CI smoke grid (``--full`` for
-the nightly grid); ``benchmarks/bench_ber.py`` writes the farm's
-trajectory into ``BENCH_ber.json``.
+the nightly grid; ``--out`` writes the farm's trajectory as JSON).
 
 Since DESIGN.md §14 the package also hosts the online
 silent-data-corruption scrubber (``verify.scrub``): the re-encode

@@ -1,14 +1,17 @@
 """Docs-consistency gate (DESIGN.md §10 satellite, wired into CI):
 
   * every ``DESIGN.md §N`` citation anywhere under src/repro/** (and in
-    benchmarks/ and README.md) must resolve to a real ``## §N`` heading
+    examples/ and README.md) must resolve to a real ``## §N`` heading
     in DESIGN.md — docstrings are the §-citation index of this repo, so
     a dangling citation means a section was renumbered or never written;
   * README code snippets must name real things: ``python -m <module>``
     targets and ``from <module> import <names>`` lines resolve, example
     script paths exist, and CLI ``--flags`` shown next to a launcher
-    are actually defined by that launcher's argparse.
+    are actually defined by that launcher's argparse;
+  * docs/BENCHMARKS.md names every cell, configuration and metric that
+    BENCHMARK.json declares.
 """
+import json
 import re
 from pathlib import Path
 
@@ -34,7 +37,6 @@ def test_design_citations_resolve():
     assert sections, "DESIGN.md has no '## §N' headings?"
     files = (
         list((REPO / "src" / "repro").rglob("*.py"))
-        + list((REPO / "benchmarks").glob("*.py"))
         + list((REPO / "examples").glob("*.py"))
         + [REPO / "README.md"]
     )
@@ -77,7 +79,7 @@ def test_readme_modules_exist():
     """Every ``python -m X`` target, ``from X import ...`` module and
     ``examples/*.py`` path in README code blocks exists; names imported
     from repro modules are real attributes."""
-    repo_pkgs = ("repro", "benchmarks", "examples", "tests")
+    repo_pkgs = ("repro", "examples", "tests")
     missing = []
     for block in _readme_blocks():
         for mod in re.findall(r"python -m ([\w.]+)", block):
@@ -106,9 +108,6 @@ def test_readme_modules_exist():
 _FLAG_SOURCES = {
     "repro.launch.serve": "src/repro/launch/serve.py",
     "serve_viterbi": "examples/serve_viterbi.py",
-    "benchmarks.run": "benchmarks/run.py",
-    "benchmarks.autotune": "benchmarks/autotune.py",
-    "benchmarks.bench_engine": "benchmarks/bench_engine.py",
     "repro.verify.farm": "src/repro/verify/farm.py",
 }
 _FLAG = re.compile(r"(?<!\S)(--[a-z][a-z-]*)\b")
@@ -132,21 +131,22 @@ def test_readme_flags_exist():
 
 
 def test_bench_artifacts_documented():
-    """docs/BENCHMARKS.md names every BENCH_* artifact the orchestrator
-    can write, and nothing else claims to be one."""
+    """docs/BENCHMARKS.md names every workload, configuration,
+    end-to-end metric and per-layer metric BENCHMARK.json declares."""
     doc = REPO / "docs" / "BENCHMARKS.md"
     assert doc.is_file(), "docs/BENCHMARKS.md missing"
     text = doc.read_text()
-    run_py = (REPO / "benchmarks" / "run.py").read_text()
-    suites = re.findall(
-        r'"([a-z_]+)": (?:lambda:|[a-z_]+\.bench\b)', run_py
-    )
-    assert len(suites) >= 7
-    undocumented = [
-        s for s in suites if f"BENCH_{s}.json" not in text
+    spec = json.loads((REPO / "BENCHMARK.json").read_text())
+    names = [
+        entry["name"]
+        for key in ("workloads", "configs", "end_to_end", "per_layer")
+        for entry in spec[key]
     ]
+    assert len(names) >= 10
+    undocumented = [n for n in names if f"`{n}`" not in text]
     assert not undocumented, (
-        f"suites missing from docs/BENCHMARKS.md: {undocumented}"
+        f"BENCHMARK.json names missing from docs/BENCHMARKS.md: "
+        f"{undocumented}"
     )
 
 

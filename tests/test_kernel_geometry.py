@@ -2,10 +2,18 @@
 common divisor of the decision depth and the chunk's step count whose
 whole kernel footprint fits the VMEM budget, capped by an explicit
 ``time_tile``."""
+import dataclasses
 import math
 
 import pytest
 
+from repro.codes.registry import get_code
+from repro.configs.viterbi_k7 import (
+    VITERBI_CELLS,
+    ViterbiConfig,
+    config_for_standard,
+)
+from repro.core import ViterbiDecoder
 from repro.core.kernel_geometry import (
     DEFAULT_BLOCK_FRAMES,
     KERNEL_VMEM_BUDGET,
@@ -90,3 +98,30 @@ def test_one_pass_tile_refuses(d, t, packed):
     assert one_pass_time_tile(d, t, S, packed) is None
     if math.gcd(d, t) >= MIN_ONE_PASS_TILE:
         assert not _fits(d, MIN_ONE_PASS_TILE, DEFAULT_BLOCK_FRAMES, packed)
+
+
+@pytest.mark.parametrize("cell", sorted(VITERBI_CELLS))
+def test_config_route_tile_matches_engine_route(cell):
+    """A decoder built from a cell's configuration picks the same
+    one-pass time tile as the engine's ``from_standard`` decoder for a
+    cell-sized chunk: the geometry follows the shape alone, and no
+    configuration field caps it."""
+    fields = {f.name for f in dataclasses.fields(ViterbiConfig)}
+    assert not fields & {"time_tile", "block_frames", "transfer_tile"}
+    code = VITERBI_CELLS[cell].code
+    by_config = ViterbiDecoder.from_config(
+        config_for_standard(code), use_kernel=True
+    )
+    by_engine = ViterbiDecoder.from_standard(code, use_kernel=True)
+    assert by_config.decision_depth == by_engine.decision_depth
+    stages = VITERBI_CELLS[cell].stream_len
+    punct = get_code(code).puncture
+    if punct is not None:  # stream_len counts kept serial LLRs
+        stages = punct.stages_for(stages)
+    rho = by_engine.rho
+    t, d = stages // rho, by_engine.decision_depth // rho
+    tile = by_engine._one_pass_tile(t, d)
+    assert by_config._one_pass_tile(t, d) == tile
+    if code == "dvb-s-r78":  # dvbs.transponders4's (T, D)
+        assert (t, d) == (28672, 4480)
+        assert tile == 896
