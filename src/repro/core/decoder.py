@@ -192,14 +192,74 @@ def _window_valid(pos: int, t_steps: int, depth_steps: int) -> int:
 
 
 @jax.jit
-def _split_frames(lam: jnp.ndarray, hist: jnp.ndarray, bits: jnp.ndarray):
-    """One-frame pieces of a fused group's carries and window bits, in
-    ONE program (DESIGN.md §10): (lam (F, S), hist (D, F, W), bits
-    (F, n)) -> F pieces of each.  Keyed on the shapes alone, so on the
-    group's total frame count (the engine's rung), never on how many
-    sessions share it."""
+def _split_frames(lam: jnp.ndarray, hist: jnp.ndarray):
+    """One-frame pieces of a fused group's carries, in ONE program
+    (DESIGN.md §10): (lam (F, S), hist (D, F, W)) -> F pieces of each.
+    Keyed on the shapes alone, so on the group's total frame count (the
+    engine's rung), never on how many sessions share it."""
     F = lam.shape[0]
-    return jnp.split(lam, F), jnp.split(hist, F, axis=1), jnp.split(bits, F)
+    return jnp.split(lam, F), jnp.split(hist, F, axis=1)
+
+
+class GroupBits:
+    """A fused group's window bits (F, n), on the device until the first
+    ``host()`` call copies the whole array to the host, once."""
+
+    __slots__ = ("device", "_host")
+
+    def __init__(self, device):
+        self.device = device
+        self._host = None
+
+    @property
+    def on_host(self) -> bool:
+        return self._host is not None
+
+    def host(self) -> np.ndarray:
+        if self._host is None:
+            self._host = np.asarray(self.device)
+        return self._host
+
+
+class BitRows:
+    """Rows [off, off + f) of a group's bits from column ``start``: one
+    session's output of ``decode_chunk_multi``.  Array-like of shape
+    (f, n - start); ``np.asarray`` copies the group to the host on the
+    first row asked for (``GroupBits.host``) and returns a view of that
+    copy, so every row of the group shares one host buffer."""
+
+    __slots__ = ("bits", "off", "f", "start")
+
+    def __init__(self, bits: GroupBits, off: int, f: int, start: int):
+        self.bits, self.off, self.f, self.start = bits, off, f, start
+
+    @property
+    def shape(self) -> Tuple[int, int]:
+        return self.f, self.bits.device.shape[1] - self.start
+
+    def __array__(self, dtype=None, copy=None):
+        rows = self.bits.host()[self.off : self.off + self.f, self.start:]
+        if copy:
+            return np.array(rows, dtype=dtype)
+        return np.asarray(rows, dtype=dtype)
+
+
+def to_host(outs) -> Tuple[list, int, int]:
+    """``np.asarray`` of each of ``outs`` (``BitRows``, device arrays or
+    host arrays): (host arrays, d2h_arrays, d2h_bytes), the device
+    buffers this call copied to the host and their bytes — one per
+    session group, however many of its rows ``outs`` holds."""
+    n = nbytes = 0
+    host = []
+    for o in outs:
+        src = o.bits if isinstance(o, BitRows) else o
+        if isinstance(src, GroupBits):
+            if not src.on_host:
+                n, nbytes = n + 1, nbytes + src.device.nbytes
+        elif isinstance(src, jax.Array):
+            n, nbytes = n + 1, nbytes + src.nbytes
+        host.append(np.asarray(o))
+    return host, n, nbytes
 
 
 @functools.partial(jax.jit, static_argnames=("tables", "final_state"))
@@ -904,7 +964,10 @@ class ViterbiDecoder:
         state with the same emission rule as ``decode_chunk``, so each
         session's emitted bits are identical to driving it alone.
 
-        Returns (new_states, outs), outs[i] of shape (f_i, m_i*rho).
+        Returns (new_states, outs), outs[i] a ``BitRows`` of shape
+        (f_i, m_i*rho).  All of them are rows of the group's one bits
+        array: the first ``np.asarray`` of any copies it to the host
+        once (``to_host`` counts that copy), the others are views.
         """
         if not states:
             return [], []
@@ -946,30 +1009,30 @@ class ViterbiDecoder:
                 self.renorm_guard.due(s.pos + T, T) for s in states):
             lam2, _ = self.renorm_guard.observe(lam2, t_chunk=T)
         new_states, outs, off = [], [], 0
+        group_bits = GroupBits(bits)
         with rec.span("decoder.split") as sp:
-            # one program splits the group into one-frame pieces; a
-            # state of several frames (the engine's pad, multi-frame
-            # callers) is sliced alone, and a state still in warm-up
-            # slices its emission window
+            # one program splits the group's carries into one-frame
+            # pieces; a state of several frames (the engine's pad,
+            # multi-frame callers) is sliced alone.  The bits stay whole:
+            # each state's output is rows of the group's one host copy,
+            # its emission window (short in warm-up) a column offset
             ops = sliced = 0
             if any(s.n_frames == 1 for s in states):
-                pieces = _split_frames(lam2, hist2, bits)
+                lams, hists = _split_frames(lam2, hist2)
                 ops += 1
             for s in states:
                 f = s.n_frames
                 if f == 1:
-                    lm, h, b = (p[off] for p in pieces)
+                    lm, h = lams[off], hists[off]
                 else:
                     lm = lam2[off : off + f]
                     h = hist2[:, off : off + f]
-                    b = bits[off : off + f]
-                    ops += 3
+                    ops += 2
                     sliced += 1
                 n_valid = _window_valid(s.pos, T, D)
-                if n_valid < T:
-                    b = b[:, (T - n_valid) * self.rho:]
-                    ops += 1
-                outs.append(b)
+                outs.append(
+                    BitRows(group_bits, off, f, (T - n_valid) * self.rho)
+                )
                 new_states.append(StreamState(lam=lm, hist=h, pos=s.pos + T))
                 off += f
             sp.set(split_ops=ops, sliced=sliced)

@@ -84,7 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.codes.puncture import depuncture_np
-from repro.core.decoder import ViterbiDecoder
+from repro.core.decoder import ViterbiDecoder, to_host
 from repro.core.kernel_geometry import (
     ENGINE_MIN_CELL,
     pick_cell_frames,
@@ -855,8 +855,9 @@ class DecodeEngine:
                     return shed + self._fail_tickets(
                         [t for t, _ in entries], e, slo, now
                     )
-                with rec.span("engine.device_wait"):
-                    bits = np.asarray(out)
+                with rec.span("engine.device_wait") as wsp:
+                    (bits,), n, nbytes = to_host([out])
+                    wsp.set(d2h_arrays=n, d2h_bytes=nbytes)
                 if self.chaos is not None and path != "soft":
                     # armed bit_flip events corrupt the decoded bits
                     # AFTER the dispatch — silent by definition; only
@@ -1377,8 +1378,11 @@ class DecodeEngine:
                         return self._session_dispatch_failed(
                             sessions, tickets, chunks, e, now, True,
                         ), False
-                with rec.span("engine.device_wait"):
-                    outs = [np.asarray(o) for o in outs]
+                with rec.span("engine.device_wait") as wsp:
+                    # the group's bits cross in one copy; each session's
+                    # rows are views of it
+                    outs, n, nbytes = to_host(outs)
+                    wsp.set(d2h_arrays=n, d2h_bytes=nbytes)
                 if self.chaos is not None and outs:
                     # fire any armed bit_flip here so corruption never
                     # leaks onto a later unrelated dispatch; sessions

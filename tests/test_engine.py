@@ -507,6 +507,82 @@ def test_session_split_keyed_on_rung():
     assert sp.attrs == {"split_ops": 1, "sliced": 0}
 
 
+def test_split_frames_splits_carries_only():
+    """The group's split program cuts the metric and ring carries into
+    one-frame pieces and nothing else: the bits are not split on the
+    device."""
+    lam = jnp.arange(3 * 4, dtype=jnp.float32).reshape(3, 4)
+    hist = jnp.arange(5 * 3 * 2, dtype=jnp.int32).reshape(5, 3, 2)
+    pieces = decoder_mod._split_frames(lam, hist)
+    assert len(pieces) == 2
+    lams, hists = pieces
+    assert [p.shape for p in lams] == [(1, 4)] * 3
+    assert [p.shape for p in hists] == [(5, 1, 2)] * 3
+    np.testing.assert_array_equal(np.asarray(hists[1]),
+                                  np.asarray(hist[:, 1:2]))
+
+
+def test_decode_chunk_multi_outs_share_one_host_buffer():
+    """Every output of a fused group, a state still in warm-up and a
+    multi-frame state included, is rows of ONE host copy of the group's
+    bits: ``to_host`` counts one device-to-host copy of the whole
+    (F, n) array, and all rows share its memory."""
+    rng = np.random.default_rng(15)
+    dec = ViterbiDecoder.from_standard("ccsds-k7", decision_depth=128)
+    c = 96  # T 48 radix steps against D 64: one chunk leaves a state
+    # in warm-up with 32 of its 48 steps emitted
+
+    def chunk(f):
+        return rng.normal(0, 1, (f, c, 2)).astype(np.float32)
+
+    states = []
+    for f, consumed in [(1, 1), (1, 2), (2, 3), (1, 4)]:
+        s = dec.init_stream_state(f, initial_state=None)
+        for _ in range(consumed):
+            s, _ = dec.decode_chunk(s, chunk(f))
+        states.append(s)
+    chunks = [chunk(s.n_frames) for s in states]
+    _, outs = dec.decode_chunk_multi(states, chunks)
+    assert [o.shape for o in outs] == [(1, 64), (1, 96), (2, 96), (1, 96)]
+    host, n, nbytes = decoder_mod.to_host(outs)
+    assert (n, nbytes) == (1, 5 * c * 4)
+    group = outs[0].bits.host()
+    assert group.shape == (5, c)
+    assert all(np.shares_memory(group, h) for h in host)
+    # a second fetch copies nothing; a copy asked for is a copy
+    assert decoder_mod.to_host(outs)[1:] == (0, 0)
+    assert not np.shares_memory(np.array(outs[1], copy=True), host[1])
+    for s, ch, h in zip(states, chunks, host):
+        _, solo = dec.decode_chunk(s, ch)
+        np.testing.assert_array_equal(h, np.asarray(solo))
+
+
+def test_session_group_bits_cross_in_one_copy():
+    """Three one-frame sessions and the engine's pad fuse into one
+    dispatch whose bits come back in ONE device-to-host copy, and each
+    ticket's bits equal the session decoded alone."""
+    rng = np.random.default_rng(16)
+    rec = SpanRecorder()
+    engine = DecodeEngine(decision_depth=64, max_batch=4, recorder=rec)
+    dec = ViterbiDecoder.from_standard("ccsds-k7", decision_depth=64)
+    c = 96
+    llrs = [rng.normal(0, 1, (c, 2)).astype(np.float32) for _ in range(3)]
+    tickets = [
+        engine.submit_chunk(engine.open_session("ccsds-k7", now=0.0), x,
+                            now=0.0)
+        for x in llrs
+    ]
+    assert len(engine.poll(now=0.0)) == 3
+    (assemble,) = rec.find("engine.assemble")
+    assert assemble.attrs == {"n_real": 3, "f": 4}
+    (wait,) = rec.find("engine.device_wait")
+    assert wait.attrs == {"d2h_arrays": 1, "d2h_bytes": 4 * c * 4}
+    for t, x in zip(tickets, llrs):
+        _, solo = dec.decode_chunk(
+            dec.init_stream_state(1, initial_state=None), x[None])
+        np.testing.assert_array_equal(t.bits, np.asarray(solo)[0])
+
+
 def test_session_groups_respect_max_batch():
     """More concurrent sessions than max_batch split into several fused
     dispatches — the frame cap holds and occupancy never exceeds 1."""

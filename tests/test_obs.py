@@ -273,9 +273,13 @@ def test_session_dispatch_decoder_spans():
     # the four host chunks stack on the host and cross in ONE copy
     assert kids["decoder.stack"].attrs["h2d_arrays"] == 1
     assert kids["decoder.stack"].attrs["h2d_bytes"] == 4 * 64 * 2 * 4
-    # one split program for the group, plus one emission-window slice
-    # per session still in warm-up (all four are fresh); no pad state
-    assert kids["decoder.split"].attrs == {"split_ops": 1 + 4, "sliced": 0}
+    # one split program for the group's carries; the emission windows
+    # of the sessions still in warm-up (all four are fresh) are cut on
+    # the host, and there is no pad state
+    assert kids["decoder.split"].attrs == {"split_ops": 1, "sliced": 0}
+    # the group's bits come back in ONE copy
+    assert kids["engine.device_wait"].attrs == {
+        "d2h_arrays": 1, "d2h_bytes": 4 * 64 * 4}
     assert len(rec.find("engine.submit")) == 0  # submitted untraced
 
 
