@@ -79,6 +79,7 @@ _K7_CCSDS = CodeSpec(k=7, polys=(0o171, 0o133))  # CCSDS / DVB-S (G1, G2)
 _K7_WIFI = CodeSpec(k=7, polys=(0o133, 0o171))  # 802.11a (g0=133 first)
 _K7_LTE = CodeSpec(k=7, polys=(0o133, 0o171, 0o165))  # 36.212 TBCC, rate 1/3
 _K5_GSM = CodeSpec(k=5, polys=(0o23, 0o33))  # GSM 05.03 CS-1
+_K9_UMTS = CodeSpec(k=9, polys=(0o557, 0o663, 0o711))  # 25.212 rate 1/3
 
 REGISTRY: Dict[str, StandardCode] = {
     c.name: c
@@ -119,6 +120,11 @@ REGISTRY: Dict[str, StandardCode] = {
         StandardCode(
             "gsm-cs1", _K5_GSM, family="gsm",
             notes="GSM 05.03 CS-1 convolutional code: (2,1,5), 23/33",
+        ),
+        StandardCode(
+            "umts-k9-r13", _K9_UMTS, family="umts",
+            notes="3GPP TS 25.212 §4.2.3.1: (3,1,9), 557/663/711, "
+            "8 zero tail bits per code block of at most 504 bits",
         ),
     ]
 }

@@ -344,13 +344,15 @@ class ViterbiDecoder:
         # only when frames-only batching underfills the device)
         self.time_parallel = time_parallel
         self.transfer_tile = transfer_tile
-        # the streaming survivor ring is ALWAYS bit-packed when the state
-        # count allows it and one-pass is on (the paper's 32-bit output
-        # compaction is part of the §8 ring design); batch/tail-biting
-        # phi packing stays opt-in via pack_survivors.
+        # the survivors a kernel writes are ALWAYS bit-packed when the
+        # state count allows it: the streaming ring (the paper's 32-bit
+        # output compaction is part of the §8 ring design) and the
+        # two-pass kernel's survivor tensor in HBM on the batch path (16
+        # words per step at 256 states, not 256 bytes).  The tail-biting
+        # and XLA paths keep packing opt-in via pack_survivors.
         self.ring_packed = (
             ring_auto_packed(spec.n_states, pack_survivors)
-            if self.one_pass else pack_survivors
+            if use_kernel or self.one_pass else pack_survivors
         )
         if puncture is not None:
             # erasure-aware depth accounting (DESIGN.md §7): punctured
@@ -575,7 +577,7 @@ class ViterbiDecoder:
                     self.rho,
                     llrs.shape[2],
                 )
-        with rec.span("decoder.launch"):
+        with rec.span("decoder.launch") as sp:
             pad = (-n) % self.rho
             if pad:
                 if final_state is not None:
@@ -591,6 +593,9 @@ class ViterbiDecoder:
             self._count_dispatch(
                 "time_parallel" if tp_tile is not None else "batch"
             )
+            if rec.enabled:
+                self._tag_launch(sp, F, (n + pad) // self.rho,
+                                 tp_tile is None)
             if tp_tile is not None:
                 from .timeparallel import decode_time_parallel
 
@@ -614,9 +619,23 @@ class ViterbiDecoder:
                     final_state=final_state,
                     precision=self.precision,
                     use_kernel=self.use_kernel,
-                    pack_survivors=self.pack_survivors,
+                    pack_survivors=self.ring_packed,
                 )
             return out[:, :n] if pad else out
+
+    def _tag_launch(self, span, n_frames: int, steps: int,
+                    sequential: bool) -> None:
+        """A frame batch's ``decoder.launch`` attributes: the trellis
+        size, the radix steps after padding, and, where the two-pass
+        kernel runs, the time tile it takes (its ``forward_plan``)."""
+        span.set(n_states=self.spec.n_states, steps=steps)
+        if sequential and self.use_kernel:
+            from repro.kernels.viterbi_acs import forward_plan
+
+            span.set(fwd_time_tile=forward_plan(
+                n_frames, steps, self.spec.n_states, self.tables.llr_block,
+                self.tables.n_slots, self.ring_packed,
+                self.precision.matmul_dtype)[2])
 
     def decode_tailbiting(
         self,

@@ -23,6 +23,7 @@ __all__ = [
     "DEFAULT_SCOPED_VMEM_BYTES",
     "VMEM_HEADROOM_BYTES",
     "KERNEL_VMEM_BUDGET",
+    "VREG_FILE_BYTES",
     "MIN_ONE_PASS_TILE",
     "MIN_TIME_PARALLEL_TILES",
     "ring_words",
@@ -64,6 +65,9 @@ VMEM_HEADROOM_BYTES = 8 * 2**20
 # it are refused up front (one-pass falls back to two-pass, the other
 # kernels shrink their tile or raise) rather than at Mosaic compile time
 KERNEL_VMEM_BUDGET = VMEM_CAPACITY_BYTES - VMEM_HEADROOM_BYTES
+# the vector register file of a v5e core: 64 vregs of 8 x 128 x 32 bits.
+# A kernel value larger than it lives in VMEM, not in registers.
+VREG_FILE_BYTES = 64 * 4096
 
 # below this time tile the one-pass kernel degenerates (a near-full ring
 # traceback per tiny tile): both streaming entry points fall back to the
@@ -141,19 +145,33 @@ def vmem_limit_bytes(need: int):
     return min(want, VMEM_CAPACITY_BYTES)
 
 
+def _step_potentials(n_states: int, n_slots: int) -> int:
+    """(R*S, BF) float32 arrays of one ACS step that Mosaic holds in
+    VMEM: the branch metrics, the routing product and their sum while
+    the (R*S, S) routing operand fits the register file (S <= 128 at
+    radix 4); beyond it the operand and the step's partial products
+    spill too.  Mosaic, compiling for a TPU v5e, then asks for about 7.7
+    such arrays: 16.5 MiB of scoped VMEM for the two-pass kernel at S
+    256, B 6, TT 128, BF 256, where three counted 13.25 MiB.  Eight
+    cover every time tile and frame block measured there."""
+    rows = n_slots * n_states
+    return 3 if vmem_bytes((rows, n_states), jnp.float32) <= (
+        VREG_FILE_BYTES) else 8
+
+
 def _acs_operand_bytes(time_tile, block_frames, n_states, llr_block,
                        n_slots, matmul_dtype) -> int:
     """VMEM shared by both frame-batched ACS kernels (frames on lanes):
     the double-buffered (TT, B, BF) LLR blocks, the entry/exit metric
     blocks and the carry scratch, the slot-major weights, and the
-    (R*S, BF) potentials of one step."""
+    (R*S, BF) potentials of one step (``_step_potentials``)."""
     S, B, R, BF = n_states, llr_block, n_slots, block_frames
     return (
         2 * vmem_bytes((time_tile, B, BF), matmul_dtype)
         + 5 * vmem_bytes((S, BF), jnp.float32)
         + 2 * vmem_bytes((R * S, B), matmul_dtype)
         + 2 * vmem_bytes((R * S, S), matmul_dtype)
-        + 3 * vmem_bytes((R * S, BF), jnp.float32)
+        + _step_potentials(S, R) * vmem_bytes((R * S, BF), jnp.float32)
     )
 
 

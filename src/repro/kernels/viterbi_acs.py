@@ -127,6 +127,18 @@ def _frame_tiling(n_frames: int, block_frames: int):
     return bf, -(-n_frames // bf) * bf
 
 
+def forward_plan(n_frames: int, n_steps: int, n_states: int,
+                 llr_block: int, n_slots: int, pack_survivors: bool,
+                 matmul_dtype=jnp.float32,
+                 block_frames: int = DEFAULT_BLOCK_FRAMES):
+    """(BF, Fp, TT): the frame block, padded frame count and time tile
+    ``acs_forward_pallas`` takes for (n_steps, n_frames) blocks; the
+    decoder's ``decoder.launch`` span reports the same TT."""
+    BF, Fp = _frame_tiling(n_frames, block_frames)
+    return BF, Fp, forward_time_tile(n_steps, BF, n_states, llr_block,
+                                     n_slots, pack_survivors, matmul_dtype)
+
+
 def _slot_major_t(w_part: jnp.ndarray, n_states: int, n_slots: int):
     """(K, S*R) columns j*R + r  ->  (R*S, K) rows r*S + j: slot r's
     operand becomes the sublane slice [r*S, (r+1)*S)."""
@@ -361,8 +373,8 @@ def acs_forward_pallas(
     S, R = n_states, n_slots
     if pack_survivors and S % 16:
         raise ValueError("pack_survivors requires n_states % 16 == 0")
-    BF, Fp = _frame_tiling(F, block_frames)
-    TT = forward_time_tile(T, BF, S, B, R, pack_survivors, matmul_dtype)
+    BF, Fp, TT = forward_plan(F, T, S, B, R, pack_survivors, matmul_dtype,
+                              block_frames)
     Tp = T + (-T) % TT
     blocks_t = jnp.pad(
         jnp.transpose(blocks, (0, 2, 1)).astype(matmul_dtype),

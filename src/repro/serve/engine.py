@@ -820,7 +820,7 @@ class DecodeEngine:
             f_cell = pick_cell_frames(k, self.max_batch)
             dec = self._decoder(code_name)
             serial = dec.puncture is not None
-            with rec.span("engine.assemble", n_real=k, f=f_cell):
+            with rec.span("engine.assemble", n_real=k, f=f_cell) as asp:
                 shape = (f_cell, l_cell) if serial else (
                     f_cell, l_cell, dec.spec.beta
                 )
@@ -829,6 +829,8 @@ class DecodeEngine:
                 for i, (_, llrs) in enumerate(entries):
                     dense[i, : llrs.shape[0]] = llrs
                     real_elems += llrs.size
+                if rec.enabled:
+                    asp.set(real_elems=real_elems, cell_elems=dense.size)
             n_stages = (
                 dec.puncture.stages_for(l_cell) if serial else l_cell
             )
@@ -1316,17 +1318,22 @@ class DecodeEngine:
             tickets, chunks, states = [], [], []
             k = len(sessions)
             f_cell = pick_cell_frames(k, self.max_batch)
-            with rec.span("engine.assemble", n_real=k, f=f_cell):
+            with rec.span("engine.assemble", n_real=k, f=f_cell) as asp:
+                real_elems = 0
                 for sess in sessions:
                     ticket, shaped = sess.pending.popleft()
                     tickets.append(ticket)
                     chunks.append(shaped)
                     states.append(sess.state)
+                    real_elems += shaped.size
                 if f_cell > k:  # pad with throwaway zero states
                     states.append(dec.init_stream_state(f_cell - k))
                     chunks.append(
                         np.zeros((f_cell - k, c, dec.spec.beta), np.float32)
                     )
+                if rec.enabled:
+                    asp.set(real_elems=real_elems,
+                            cell_elems=f_cell * c * dec.spec.beta)
             key = (code_name, "session", f_cell, c)
             with rec.span("engine.jit_lookup", path="session"):
                 if key in self._fns:

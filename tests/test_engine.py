@@ -574,7 +574,8 @@ def test_session_group_bits_cross_in_one_copy():
     ]
     assert len(engine.poll(now=0.0)) == 3
     (assemble,) = rec.find("engine.assemble")
-    assert assemble.attrs == {"n_real": 3, "f": 4}
+    assert assemble.attrs == {"n_real": 3, "f": 4, "real_elems": 3 * c * 2,
+                              "cell_elems": 4 * c * 2}
     (wait,) = rec.find("engine.device_wait")
     assert wait.attrs == {"d2h_arrays": 1, "d2h_bytes": 4 * c * 4}
     for t, x in zip(tickets, llrs):

@@ -18,9 +18,12 @@ from repro.core.kernel_geometry import (
     DEFAULT_BLOCK_FRAMES,
     KERNEL_VMEM_BUDGET,
     MIN_ONE_PASS_TILE,
+    forward_time_tile,
+    forward_vmem_bytes,
     fused_decode_vmem_bytes,
     one_pass_time_tile,
     pick_time_tile,
+    vmem_limit_bytes,
 )
 
 S, B, R = 64, 4, 4  # K=7, radix-4
@@ -125,3 +128,27 @@ def test_config_route_tile_matches_engine_route(cell):
     if code == "dvb-s-r78":  # dvbs.transponders4's (T, D)
         assert (t, d) == (28672, 4480)
         assert tile == 896
+
+
+MiB = 2**20
+
+
+def test_two_pass_count_at_256_states():
+    """The two-pass kernel at K=9 rate 1/3 (S 256, B 6), packed, at the
+    UMTS cell's tile (TT 128, BF 256): Mosaic asks for 16.47 MiB of
+    scoped VMEM, where three counted potentials made 13.25 MiB (compile
+    rehearsal); eight (R*S, BF) float32 potentials make 18.25 MiB, and
+    the limit is raised to that plus the headroom.  Tiles follow the
+    step count (the 512- and 128-stage rungs, 256 and 64 steps).  The
+    counts at 64 states are the same as before."""
+    need = forward_vmem_bytes(128, 256, 256, 6, 4, True)
+    assert need == 13 * MiB + MiB // 4 + 5 * MiB
+    assert need > int(16.47 * MiB)
+    assert vmem_limit_bytes(need) == need + 8 * MiB
+    assert [forward_time_tile(t, 256, 256, 6, 4, True)
+            for t in (256, 64, 32)] == [128, 64, 32]
+    assert forward_vmem_bytes(128, 256, S, B, R, True) == 3_735_552
+    assert fused_decode_vmem_bytes(2560, 512, 256, S, B, R, True) == (
+        19_464_192)
+    assert fused_decode_vmem_bytes(2560, 512, 256, S, B, R, False) == (
+        57_212_928)

@@ -7,6 +7,8 @@ three Pallas kernels with Mosaic (``interpret=False``) at the
 radix-4), check that each lowers to a ``tpu_custom_call``, check that
 the padding-aware VMEM guards admit and refuse the same shapes Mosaic
 does, and check that the XLA decode of the whole cell fits 16 GiB of HBM.
+The two-pass kernel is also compiled at 256 states (the UMTS K=9 rate
+1/3 code) at the ``umts.rmc1536`` cell's shapes.
 Nothing here runs: a compile that passes is not a chip run.
 
 All of it lives in this one file so one test worker loads the TPU
@@ -22,11 +24,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.codes import get_code
 from repro.core import CODE_K7_CCSDS, build_acs_tables
 from repro.core.kernel_geometry import (
     DEFAULT_BLOCK_FRAMES,
     KERNEL_VMEM_BUDGET,
     VMEM_CAPACITY_BYTES,
+    forward_time_tile,
+    forward_vmem_bytes,
     fused_decode_vmem_bytes,
     one_pass_time_tile,
     pick_transfer_tile,
@@ -46,6 +51,7 @@ S, R, B = SPEC.n_states, 1 << RHO, RHO * SPEC.beta
 DEPTH_STEPS = 5120 // RHO  # default decision depth
 CHUNK_STEPS = 4096 // RHO  # default chunk length
 HBM_BYTES = 16 * 2**30
+K9 = get_code("umts-k9-r13").spec  # 256 states, rate 1/3: B = 6
 
 
 @pytest.fixture(scope="module")
@@ -102,6 +108,63 @@ def test_two_pass_kernel_compiles_at_decode_64k(one_chip, w, packed):
         w,
     )
     assert "tpu_custom_call" in text
+
+
+def _w_sds(spec, sharding):
+    tables = build_acs_tables(spec, RHO)
+    return jax.ShapeDtypeStruct(tables.fused_w.shape, jnp.float32,
+                                sharding=sharding)
+
+
+@pytest.mark.parametrize("f,steps", [(1024, 256), (512, 64)],
+                         ids=["dtch_1024", "dcch_512"])
+def test_two_pass_kernel_compiles_at_umts_rmc1536(one_chip, f, steps):
+    """256 states, packed survivors (16 words per frame-step): the DTCH
+    batch (1024 frames on the 512-stage rung) and a DCCH batch (512
+    frames on the 128-stage rung) of the ``umts.rmc1536`` cell."""
+    n_states, b = K9.n_states, RHO * K9.beta
+    text, _ = _compile(
+        lambda x, l, w: acs_forward_pallas(
+            x, l, w, n_states=n_states, n_slots=R, pack_survivors=True,
+            interpret=False,
+        ),
+        _sds((steps, f, b), jnp.float32, one_chip),
+        _sds((f, n_states), jnp.float32, one_chip),
+        _w_sds(K9, one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("spec", [SPEC, K9], ids=["k7", "k9"])
+def test_two_pass_count_is_what_mosaic_needs(one_chip, spec, monkeypatch):
+    """The two-pass kernel compiles with its scoped-VMEM limit set to
+    the counted footprint alone, no headroom: the count covers what
+    Mosaic allocates.  At 256 states three counted potentials left
+    3.2 MiB out, and Mosaic refused the kernel at that limit."""
+    from repro.kernels import viterbi_acs
+    from repro.kernels.viterbi_acs import _acs_weights, _forward_call
+
+    n_states, b, bf = spec.n_states, RHO * spec.beta, DEFAULT_BLOCK_FRAMES
+    tt = forward_time_tile(256, bf, n_states, b, R, True)
+    need = forward_vmem_bytes(tt, bf, n_states, b, R, True)
+    monkeypatch.setattr(viterbi_acs, "vmem_limit_bytes", lambda n: n)
+
+    def launch(x, l, w):
+        theta, pred = _acs_weights(w, n_states, R, b, jnp.float32)
+        return _forward_call(
+            x, l, theta, pred, n_states=n_states, n_slots=R, n_steps=256,
+            time_tile=tt, block_frames=bf, carry_dtype=jnp.float32,
+            matmul_dtype=jnp.float32, renorm=True, pack_survivors=True,
+            semiring="tropical", interpret=False,
+        )
+
+    text, _ = _compile(
+        launch,
+        _sds((256, b, 2 * bf), jnp.float32, one_chip),
+        _sds((n_states, 2 * bf), jnp.float32, one_chip),
+        _w_sds(spec, one_chip),
+    )
+    assert "tpu_custom_call" in text and need < KERNEL_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("packed", [False, True], ids=["i8", "packed"])
